@@ -8,7 +8,8 @@ steady state — verified by the dry-run (EXPERIMENTS.md §Dry-run) — and
 per-host expert I/O is bounded by ``B / n_hosts``.
 
 Layout: model parameters are flattened, padded, and viewed as a block
-matrix ``(NB, W)`` with ``W = block_size / 4`` float32 elements per block.
+matrix ``(NB, W)`` with ``W = block_size / itemsize`` elements per block
+(the stored dtype's itemsize; the math runs in float32).
 The plan's selection becomes a dense ``(K, NB)`` mask that gates expert
 deltas; zeroed (unselected) deltas are mathematically inert for every
 operator (TA/DARE: zero contribution; AVG: per-block count divisor;

@@ -106,9 +106,10 @@ class PipelineConfig:
     kernel            — "numpy": vectorized numpy apply, bit-identical to
                         the stream path (default; the golden-test
                         invariant).  "jax": the jitted kernel wrappers in
-                        :mod:`repro.kernels.ops` (Pallas on TPU) —
-                        tolerance-level equivalent on CPU, use on
-                        accelerators.
+                        :mod:`repro.kernels.ops` (compiled Pallas on
+                        TPU, jnp elsewhere; the run stats' ``backend``
+                        names which) — tolerance-level equivalent to
+                        the numpy kernel.
     """
 
     window_blocks: int = 32
@@ -1060,6 +1061,9 @@ class _PipelineEngine:
             "prefetch_windows": self.cfg.prefetch_windows,
             "read_threads": self.cfg.read_threads,
             "kernel": self.cfg.kernel,
+            # the implementation the kernel dispatched to (repro.kernels.ops)
+            "backend": (self.kernel_ops.backend()
+                        if self.kernel_ops is not None else "numpy"),
             "coalesce_gap_bytes": self.cfg.coalesce_gap_bytes,
             "peak_resident_blocks": self.gauge.peak,
             "resident_bound": self.cfg.max_resident_blocks(n_experts),

@@ -49,7 +49,7 @@ from repro.core.plan import MergePlan
 from repro.core.transactions import TransactionManager
 from repro.dist.lease import DistOptions, ShardLease
 from repro.dist.partition import Partition, partition_plan
-from repro.dist.transport import make_transport
+from repro.dist.transport import holds_accelerator, make_transport
 from repro.store.journal import journal_path
 from repro.store.snapshot import SnapshotStore
 
@@ -84,6 +84,13 @@ def run_sharded_merge(
     t0 = time.time()
     options = options or DistOptions()
     options.validate()
+    if (options.transport == "process" and options.kernel != "numpy"
+            and holds_accelerator()):
+        raise RuntimeError(
+            "this process holds the accelerator; a %r device worker it "
+            "spawned could not use it — run the merge from a process that "
+            "has not started JAX, or use the inline transport"
+            % options.kernel)
     stats = snapshots.stats
     expert_read_before = stats.c_expert
     txn = txn or TransactionManager(snapshots, catalog)
@@ -312,6 +319,7 @@ def run_sharded_merge(
                 "realized_expert_blocks": doc["realized_expert_blocks"],
                 "resumed_blocks": doc.get("resumed_blocks", 0),
                 "seconds": doc["seconds"],
+                "pipeline": doc.get("pipeline"),
             }
             for k, doc in sorted(docs.items())
         ],

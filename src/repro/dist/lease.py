@@ -35,8 +35,13 @@ class DistOptions:
     ``kernel`` selects the worker's compute path: the bit-identical
     ``"numpy"`` stream kernel, the jitted ``"jax"`` block kernel, or
     ``"mesh"`` — the packed whole-tensor device path of
-    ``core.distributed.build_merge_step`` (tolerance-level on TIES tail
-    blocks; forces tensor-aligned shard cuts).
+    ``core.distributed.build_merge_step`` over every local device
+    (tolerance-level on TIES tail blocks; forces tensor-aligned shard
+    cuts).  A chip belongs to one process, so a device kernel
+    (``"jax"``/``"mesh"``) under the process transport runs one worker
+    process (``n_workers=1``), spawned by a coordinator that holds no
+    accelerator itself; other configurations are refused before any
+    lease is issued.
     """
 
     n_workers: int = 2
@@ -63,6 +68,13 @@ class DistOptions:
                 % (self.kernel, ", ".join(KERNELS)))
         if self.max_lease_attempts < 1:
             raise ValueError("max_lease_attempts must be >= 1")
+        if (self.transport == "process" and self.kernel != "numpy"
+                and self.n_workers > 1):
+            raise ValueError(
+                "kernel %r under the process transport needs n_workers=1: "
+                "each device worker takes every local chip, and a chip "
+                "belongs to one process (got n_workers=%d)"
+                % (self.kernel, self.n_workers))
 
 
 @dataclasses.dataclass

@@ -36,6 +36,22 @@ from repro.testing.chaos import SimulatedCrash
 CRASH_EXIT = 3
 
 
+def holds_accelerator() -> bool:
+    """True when this process has started a non-CPU JAX backend.  It then
+    owns the chip until it exits, and a device worker it spawns would
+    fail on the accelerator library's lock or fall back to the CPU."""
+    if "jax" not in sys.modules:
+        return False
+    # no public API says whether backends are up without starting them
+    from jax._src import xla_bridge
+
+    if not xla_bridge.backends_are_initialized():
+        return False
+    import jax
+
+    return jax.default_backend() != "cpu"
+
+
 @dataclasses.dataclass
 class WorkerExit:
     """Terminal state of one lease attempt."""

@@ -321,11 +321,14 @@ def _run_mesh(
     touch: Dict[str, List[int]],
     coverage_rows: List[Tuple[str, int, str]],
 ) -> Tuple[int, Dict]:
-    """Device-compute path: pack this shard's (whole) tensors into the
-    (NB, W) block matrix and apply ``core.distributed.build_merge_step``
-    once.  Requires tensor-aligned spans (the partitioner enforces this
-    for ``kernel="mesh"``).  Tolerance-level on TIES tail blocks — see
-    the pack_arrays docstring and tests."""
+    """Device-compute path: pack this shard's (whole) tensors into
+    (NB, W) block matrices — one per stored itemsize, so a packed row is
+    exactly one plan block (``W = block_size // itemsize``) — and apply
+    ``core.distributed.build_merge_step`` over every local device.  The
+    packed rows are zero-padded to a multiple of the device count.
+    Requires tensor-aligned spans (the partitioner enforces this for
+    ``kernel="mesh"``).  Tolerance-level on TIES tail blocks — see the
+    pack_arrays docstring and tests."""
     import jax  # lazy: workers default to the numpy kernel
 
     from repro.core.distributed import (
@@ -337,8 +340,7 @@ def _run_mesh(
     )
     from jax.sharding import Mesh
 
-    W = lease.block_size // 4
-    merge_tensors: List[str] = []
+    merge_by_itemsize: Dict[int, List[str]] = {}
     pass_through: Dict[str, List[np.ndarray]] = {}
     base_arrays: Dict[str, np.ndarray] = {}
     specs: Dict[str, object] = {}
@@ -363,7 +365,7 @@ def _run_mesh(
         base_blocks[t] = blocks
         rev = plan.reverse_index(t)
         if _is_mergeable(spec) and rev:
-            merge_tensors.append(t)
+            merge_by_itemsize.setdefault(spec.dtype.itemsize, []).append(t)
             base_arrays[t] = np.concatenate(
                 [np.asarray(b, np.float32).reshape(-1) for b in blocks]
             ).reshape(spec.shape)
@@ -372,46 +374,47 @@ def _run_mesh(
 
     pipe_stats = {"kernel": "mesh", "windows": 0}
     out_arrays: Dict[str, np.ndarray] = {}
-    if merge_tensors:
-        arrays = {t: base_arrays[t] for t in merge_tensors}
-        packed, metas = pack_arrays(arrays, W)
-        n_packed = packed.shape[0]
-        offsets = {name: off for name, _s, _n, off in metas}
-        experts = np.zeros(
-            (len(plan.expert_ids), n_packed, W), np.float32)
-        for t in merge_tensors:
-            D = DeltaIterator(t, plan, base_reader, expert_readers,
-                              coalesce=lease.coalesce)
-            rev = plan.reverse_index(t)
-            for b in sorted(rev):
-                x0 = base_blocks[t][b]
-                deltas, eidxs, eids = D.pull(b, x0)
-                realized += len(eids)
-                if eids:
-                    touch.setdefault(t, []).append(b)
-                    coverage_rows.append((t, b, ",".join(eids)))
-                for row, ei in enumerate(eidxs):
-                    d = np.asarray(deltas[row], np.float32).reshape(-1)
-                    experts[ei, offsets[t] + b, : d.size] = d
-        select = selection_mask(plan, metas, W, n_packed)
-        masks = None
-        if plan.op.lower() == "dare":
-            masks = dare_masks_packed(plan, metas, W, n_packed)
+    if merge_by_itemsize:
         devs = jax.devices()
-        n_dev = max(
-            d for d in range(1, len(devs) + 1) if n_packed % d == 0
-        ) if n_packed else 1
-        mesh = Mesh(np.array(devs[:n_dev]), ("all",))
-        kind = "delta"  # DeltaIterator already materialized deltas
-        step = build_merge_step(mesh, plan.op.lower(), theta, kind=kind,
+        mesh = Mesh(np.array(devs), ("all",))
+        # DeltaIterator already materialized deltas
+        step = build_merge_step(mesh, plan.op.lower(), theta, kind="delta",
                                 donate=False)
-        args = [packed, experts, select]
-        if masks is not None:
-            args.append(masks)
-        out = np.asarray(step(*args))
-        out_arrays = unpack_arrays(out, metas)
-        pipe_stats["mesh_devices"] = n_dev
-        pipe_stats["packed_blocks"] = int(n_packed)
+        n_packed_total = 0
+        for itemsize, merge_tensors in sorted(merge_by_itemsize.items()):
+            W = plan.block_size // itemsize
+            packed, metas = pack_arrays(
+                {t: base_arrays[t] for t in merge_tensors}, W)
+            n_packed = packed.shape[0]
+            n_packed_total += n_packed
+            # zero rows are inert for every operator (nothing selected)
+            n_rows = -(-n_packed // len(devs)) * len(devs)
+            packed = np.pad(packed, ((0, n_rows - n_packed), (0, 0)))
+            offsets = {name: off for name, _s, _n, off in metas}
+            experts = np.zeros(
+                (len(plan.expert_ids), n_rows, W), np.float32)
+            for t in merge_tensors:
+                D = DeltaIterator(t, plan, base_reader, expert_readers,
+                                  coalesce=lease.coalesce)
+                rev = plan.reverse_index(t)
+                for b in sorted(rev):
+                    x0 = base_blocks[t][b]
+                    deltas, eidxs, eids = D.pull(b, x0)
+                    realized += len(eids)
+                    if eids:
+                        touch.setdefault(t, []).append(b)
+                        coverage_rows.append((t, b, ",".join(eids)))
+                    for row, ei in enumerate(eidxs):
+                        d = np.asarray(deltas[row], np.float32).reshape(-1)
+                        experts[ei, offsets[t] + b, : d.size] = d
+            args = [packed, experts, selection_mask(plan, metas, W, n_rows)]
+            if plan.op.lower() == "dare":
+                args.append(dare_masks_packed(plan, metas, W, n_rows))
+            out = np.asarray(step(*args))
+            out_arrays.update(unpack_arrays(out, metas))
+        pipe_stats["mesh_devices"] = len(devs)
+        pipe_stats["backend"] = "xla-" + devs[0].platform
+        pipe_stats["packed_blocks"] = int(n_packed_total)
 
     for t in plan.tensor_order:
         if t not in spans:
@@ -422,7 +425,7 @@ def _run_mesh(
         covered = {b: csv for tt, b, csv in coverage_rows if tt == t}
         if t in out_arrays:
             flat = np.asarray(out_arrays[t], np.float32).reshape(-1)
-            elems = plan.block_size // 4
+            elems = plan.block_size // spec.dtype.itemsize
             for b in range(n_blocks):
                 chunk = flat[b * elems: (b + 1) * elems]
                 src = base_blocks[t][b]

@@ -15,11 +15,13 @@ Merging has arithmetic intensity < 1 FLOP/byte, so the kernels are
 HBM-bandwidth-bound by construction; the win is the fusion, not FLOPs.
 
 TIES trim thresholds (a per-row top-k) are computed *outside* the kernel
-by XLA's optimized sort (see ops.py) and passed in as a (NB, K) operand —
-sorting inside a VPU kernel would waste the fused pass.
+(``ref.ties_thresholds``, an exact bisection) and passed in as a (NB, K)
+operand — selecting inside a VPU kernel would waste the fused pass.
 
-The container is CPU-only: kernels are validated with ``interpret=True``
-(kernel body executed in Python) against :mod:`repro.kernels.ref`.
+Tests validate the kernels with ``interpret=True`` (kernel body executed
+in Python on the CPU) against :mod:`repro.kernels.ref`, and
+``tests/test_tpu_compile.py`` compiles them for a described v5e chip;
+``chip_smoke.py`` runs them compiled on the TPU.
 """
 from __future__ import annotations
 

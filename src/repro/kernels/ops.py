@@ -1,13 +1,16 @@
 """Jitted wrappers + dispatch for the merge kernels.
 
 ``merge_blocks(op, x0s, Ds, theta, masks=None)`` is the single entry used
-by the executor's batched path and the distributed merge step.  Backend
-selection:
+by the executor's batched and pipelined paths.  The implementation is
+picked from the platform JAX runs on, never from a fallback:
 
-    * TPU          -> Pallas kernels (compiled)
-    * CPU/other    -> pure-jnp reference (XLA-fused; Pallas interpret mode
-                      is Python-per-tile and only used for validation)
-    * REPRO_FORCE_PALLAS=1 -> Pallas with interpret fallback (tests)
+    * TPU          -> Pallas kernels, compiled
+    * CPU/other    -> pure-jnp reference (XLA-fused)
+    * interpret=True (tests only) -> Pallas kernels in interpret mode
+
+:func:`backend` names the implementation a call dispatches to; the
+pipelined engine records it in its run stats.  A backend that fails to
+start raises here — it is never read as "not a TPU".
 
 Inputs may be any float dtype; math runs in float32 and the result is
 cast back (matching the streaming executor's numpy semantics).
@@ -15,8 +18,7 @@ cast back (matching the streaming executor's numpy semantics).
 from __future__ import annotations
 
 import functools
-import os
-from typing import Dict, Optional
+from typing import Dict
 
 import jax
 import jax.numpy as jnp
@@ -27,18 +29,17 @@ from repro.kernels import ref
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except (RuntimeError, IndexError):  # pragma: no cover — no backend
-        return False
+    return jax.default_backend() == "tpu"
 
 
-def _force_pallas() -> bool:
-    return os.environ.get("REPRO_FORCE_PALLAS", "0") == "1"
-
-
-def use_pallas() -> bool:
-    return _on_tpu() or _force_pallas()
+def backend(interpret: bool = False) -> str:
+    """The implementation :func:`merge_blocks` dispatches to:
+    ``"pallas-tpu"`` (compiled Pallas), ``"pallas-interpret"`` or
+    ``"jnp-<platform>"``."""
+    if interpret:
+        return "pallas-interpret"
+    platform = jax.default_backend()
+    return "pallas-tpu" if platform == "tpu" else "jnp-" + platform
 
 
 def _pad_to(x: jnp.ndarray, mult: int, axis: int) -> jnp.ndarray:
@@ -51,7 +52,8 @@ def _pad_to(x: jnp.ndarray, mult: int, axis: int) -> jnp.ndarray:
     return jnp.pad(x, widths)
 
 
-def _pallas_padded(fn, x0, D, *extras, tb=mb.TILE_NB, tw=mb.TILE_W, **kw):
+def _pallas_padded(fn, x0, D, *extras, interpret: bool,
+                   tb=mb.TILE_NB, tw=mb.TILE_W, **kw):
     """Pad (NB, W) to tile multiples, run the kernel, slice back."""
     nb, w = x0.shape
     tw = min(tw, max(128, ((w + 127) // 128) * 128))
@@ -63,8 +65,27 @@ def _pallas_padded(fn, x0, D, *extras, tb=mb.TILE_NB, tw=mb.TILE_W, **kw):
         if e.ndim == 3:
             e = _pad_to(e, tw, 2)
         extras_p.append(e)
-    out = fn(x0p, Dp, *extras_p, tb=tb, tw=tw, interpret=not _on_tpu(), **kw)
+    out = fn(x0p, Dp, *extras_p, tb=tb, tw=tw, interpret=interpret, **kw)
     return out[:nb, :w]
+
+
+# pad + kernel + slice as one program: one compile per input shape
+@functools.partial(jax.jit, static_argnames=("coeff", "interpret"))
+def _linear_pallas(x0, D, coeff, interpret):
+    return _pallas_padded(mb.linear_merge_pallas, x0, D, coeff=coeff,
+                          interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("lam", "interpret"))
+def _ties_pallas(x0, D, thresh, lam, interpret):
+    return _pallas_padded(mb.ties_merge_pallas, x0, D, thresh, lam=lam,
+                          interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("density", "lam", "interpret"))
+def _dare_pallas(x0, D, m, density, lam, interpret):
+    return _pallas_padded(mb.dare_merge_pallas, x0, D, m, density=density,
+                          lam=lam, interpret=interpret)
 
 
 # --------------------------------------------------------------- public API
@@ -74,34 +95,36 @@ def merge_blocks(
     Ds,
     theta: Dict,
     masks=None,
+    interpret: bool = False,
 ) -> np.ndarray:
     """Apply operator ``op`` to a batch of blocks.
 
     x0s (NB, W) float; Ds (NB, K, W); masks (NB, K, W) for DARE.
-    Returns float32 ndarray (NB, W).
+    Returns float32 ndarray (NB, W).  ``interpret=True`` runs the Pallas
+    kernels in interpret mode on any platform (tests only).
     """
     x0 = jnp.asarray(x0s, jnp.float32)
     D = jnp.asarray(Ds, jnp.float32)
     lam = float(theta.get("lam", 1.0))
     op = op.lower()
-    pallas = use_pallas()
+    pallas = interpret or _on_tpu()
 
     if op == "avg":
         k = D.shape[1]
         if pallas:
-            out = _pallas_padded(mb.linear_merge_pallas, x0, D, coeff=1.0 / (k + 1))
+            out = _linear_pallas(x0, D, 1.0 / (k + 1), interpret)
         else:
             out = _avg_jit(x0, D)
     elif op == "ta":
         if pallas:
-            out = _pallas_padded(mb.linear_merge_pallas, x0, D, coeff=lam)
+            out = _linear_pallas(x0, D, lam, interpret)
         else:
             out = _ta_jit(x0, D, lam)
     elif op == "ties":
         trim = float(theta.get("trim_frac", 0.2))
         thresh = _ties_thresh_jit(D, trim)
         if pallas:
-            out = _pallas_padded(mb.ties_merge_pallas, x0, D, thresh, lam=lam)
+            out = _ties_pallas(x0, D, thresh, lam, interpret)
         else:
             out = _ties_apply_jit(x0, D, thresh, lam)
     elif op == "dare":
@@ -110,9 +133,7 @@ def merge_blocks(
         m = jnp.asarray(masks)
         density = float(theta.get("density", 0.5))
         if pallas:
-            out = _pallas_padded(
-                mb.dare_merge_pallas, x0, D, m, density=density, lam=lam
-            )
+            out = _dare_pallas(x0, D, m, density, lam, interpret)
         else:
             out = _dare_jit(x0, D, m, density, lam)
     else:
@@ -146,15 +167,15 @@ def _dare_jit(x0, D, m, density, lam):
     return ref.dare_ref(x0, D, m, density, lam)
 
 
-def sketch_blocks(x) -> np.ndarray:
+def sketch_blocks(x, interpret: bool = False) -> np.ndarray:
     """(NB, W) -> (NB, 3) [l2, absmax, mean] (ANALYZE on-device path)."""
     xj = jnp.asarray(x, jnp.float32)
-    if use_pallas():
+    if interpret or _on_tpu():
         nb, w = xj.shape
         tw = min(mb.TILE_W, max(128, ((w + 127) // 128) * 128))
         xp = _pad_to(_pad_to(xj, mb.TILE_NB, 0), tw, 1)
         stats = mb.sketch_blocks_pallas(
-            xp, tb=mb.TILE_NB, tw=tw, interpret=not _on_tpu()
+            xp, tb=mb.TILE_NB, tw=tw, interpret=interpret
         )[:nb]
     else:
         stats = _sketch_jit(xj)

@@ -17,15 +17,32 @@ from jax import lax
 
 
 def ties_thresholds(D: jnp.ndarray, trim_frac: float) -> jnp.ndarray:
-    """keep-th largest |Δ| per (block, expert) row; keep = round(ρ·W)."""
+    """keep-th largest |Δ| per (block, expert) row; keep = round(ρ·W).
+
+    Found exactly by bisection on the float32 bit pattern (for
+    non-negative floats it orders like the value): the result is the
+    largest ``t`` with at least ``keep`` elements ``>= t``, which is the
+    keep-th largest element itself.  A sort gives the same value, but on
+    the TPU a sort of a 65,536-wide row takes ~20 s to compile for each
+    window shape; the 31 counting passes take ~0.3 s and no sorted copy.
+    """
     nb, k, w = D.shape
     keep = max(1, int(round(trim_frac * w)))
     if keep >= w:
         return jnp.full((nb, k), -jnp.inf, dtype=jnp.float32)
-    absd = jnp.abs(D)
-    # sorted ascending, element [w - keep] == keep-th largest
-    srt = jnp.sort(absd, axis=-1)
-    return srt[..., w - keep]
+    bits = lax.bitcast_convert_type(jnp.abs(D.astype(jnp.float32)), jnp.int32)
+    # invariant: count(bits >= lo) >= keep > count(bits >= hi)
+    lo = jnp.zeros((nb, k), jnp.int32)
+    hi = jnp.full((nb, k), jnp.iinfo(jnp.int32).max, jnp.int32)
+
+    def halve(_, bounds):
+        lo, hi = bounds
+        mid = lo + (hi - lo) // 2
+        ok = jnp.sum(bits >= mid[..., None], axis=-1) >= keep
+        return jnp.where(ok, mid, lo), jnp.where(ok, hi, mid)
+
+    lo, _ = lax.fori_loop(0, 31, halve, (lo, hi))
+    return lax.bitcast_convert_type(lo, jnp.float32)
 
 
 def avg_ref(x0: jnp.ndarray, D: jnp.ndarray) -> jnp.ndarray:

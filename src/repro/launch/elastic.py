@@ -26,7 +26,7 @@ from __future__ import annotations
 
 from typing import Optional, Tuple
 
-import jax
+from repro.launch.mesh import auto_mesh
 
 
 def replan_mesh(
@@ -48,11 +48,11 @@ def replan_mesh(
     if want_pods and want_pods > 1:
         if data % want_pods:
             raise ValueError(f"data axis {data} not divisible by {want_pods} pods")
-        return jax.make_mesh(
+        return auto_mesh(
             (want_pods, data // want_pods, model_parallel),
             ("pod", "data", "model"),
         )
-    return jax.make_mesh((data, model_parallel), ("data", "model"))
+    return auto_mesh((data, model_parallel), ("data", "model"))
 
 
 def degraded_batch(global_batch: int, lost_fraction: float) -> int:

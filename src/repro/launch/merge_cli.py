@@ -907,6 +907,10 @@ def main() -> None:
     ap.add_argument("--explain", default=None, metavar="SID",
                     help="print the audit record for a snapshot and exit")
     args = ap.parse_args()
+    if args.compute == "batched" or args.pipeline_kernel == "jax":
+        from repro.launch.compile_cache import enable_compile_cache
+
+        enable_compile_cache()
 
     if args.explain:
         mp = MergePipe(args.workspace, block_size=args.block_size)

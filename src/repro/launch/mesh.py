@@ -19,18 +19,26 @@ from typing import Optional, Tuple
 import jax
 
 
+def auto_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
+    """``jax.make_mesh`` with Auto axes: the sharding rules constrain
+    activations with ``with_sharding_constraint``, which refuses the
+    Explicit axes ``make_mesh`` builds by default."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(shape))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_debug_mesh(n_devices: Optional[int] = None):
     """Tiny mesh over whatever devices exist (CPU tests)."""
     n = n_devices or len(jax.devices())
     if n >= 4:
-        return jax.make_mesh((2, n // 2), ("data", "model"))
-    return jax.make_mesh((1, n), ("data", "model"))
+        return auto_mesh((2, n // 2), ("data", "model"))
+    return auto_mesh((1, n), ("data", "model"))
 
 
 def mesh_info(mesh) -> Tuple[int, dict]:

@@ -38,6 +38,10 @@ def main(argv: Optional[list] = None) -> int:
                     help="where to write the result doc")
     args = ap.parse_args(argv)
     lease = ShardLease.read(args.lease)
+    if lease.kernel != "numpy":
+        from repro.launch.compile_cache import enable_compile_cache
+
+        enable_compile_cache()
     try:
         run_worker(args.workspace, lease, result_path=args.result)
     except SimulatedCrash as e:

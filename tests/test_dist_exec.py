@@ -192,3 +192,100 @@ def test_sharded_single_worker_degenerates_to_local(tmp_path):
     _assert_identical(sess, "local", "shard")
     assert len(r.stats["shards"]) == 1
     sess.close()
+
+
+# ------------------------------------------------- device kernels on workers
+def _bf16_workspace(tmp_path):
+    """Experts stored in bf16, as fine-tune fleets ship: a mesh row holds
+    block_size / 2 elements, not block_size / 4."""
+    from repro.store.dtypes import bfloat16
+
+    sess = Session(str(tmp_path / "ws"), block_size=BS)
+    base, experts = make_models(n_experts=3)
+    sess.register_model("base", {k: v.astype(bfloat16) for k, v in base.items()})
+    ids = []
+    for i, e in enumerate(experts):
+        sess.register_model(f"ex{i}", {k: v.astype(bfloat16) for k, v in e.items()})
+        ids.append(f"ex{i}")
+    return sess, ids
+
+
+@pytest.mark.parametrize("op,theta", [
+    ("ta", {"lam": 0.5}),
+    ("ties", {"trim_frac": 0.3}),
+])
+def test_mesh_kernel_bf16_matches_stream(tmp_path, op, theta):
+    sess, ids = _bf16_workspace(tmp_path)
+    _run(sess, ids, "ref", op=op, theta=theta, compute="stream")
+    r = _run(sess, ids, "mesh", op=op, theta=theta,
+             dist=DistOptions(n_workers=1, kernel="mesh", transport="inline"))
+    (shard,) = r.stats["shards"]
+    assert shard["attempts"] == 1
+    assert shard["pipeline"]["mesh_devices"] == len(__import__("jax").devices())
+    assert shard["pipeline"]["backend"].startswith("xla-")
+    ref, got = sess.load("ref"), sess.load("mesh")
+    # float32 math in another order: results agree to one bf16 step
+    w = BS // 2
+    for t in ref:
+        a = np.asarray(ref[t], np.float32)
+        b = np.asarray(got[t], np.float32)
+        assert got[t].dtype == ref[t].dtype
+        if op == "ties" and a.size % w:
+            # the mesh trims TIES over the zero-padded tail block (see
+            # test_distributed.py::test_ties_tail_block_deviation_bounded)
+            continue
+        np.testing.assert_allclose(b, a, rtol=2 ** -7, atol=2 ** -16)
+    sess.close()
+
+
+@pytest.mark.parametrize("kernel", ["jax", "mesh"])
+def test_device_kernel_workers_never_share_a_chip(tmp_path, monkeypatch, kernel):
+    """Two device-kernel worker processes would contend for one chip: the
+    configuration is refused before any lease is issued."""
+    from repro.dist import transport
+
+    def no_launch(*a, **kw):
+        raise AssertionError("a lease was issued")
+
+    monkeypatch.setattr(transport.LocalProcessTransport, "launch", no_launch)
+    sess, ids = _workspace(tmp_path, "ws")
+    with pytest.raises(ValueError, match="n_workers=1"):
+        _run(sess, ids, "shard",
+             dist=DistOptions(n_workers=2, kernel=kernel, transport="process"))
+    sess.close()
+
+
+def test_coordinator_holding_the_chip_spawns_no_device_worker(
+        tmp_path, monkeypatch):
+    from repro.dist import coordinator, transport
+
+    def no_launch(*a, **kw):
+        raise AssertionError("a lease was issued")
+
+    monkeypatch.setattr(transport.LocalProcessTransport, "launch", no_launch)
+    monkeypatch.setattr(coordinator, "holds_accelerator", lambda: True)
+    sess, ids = _workspace(tmp_path, "ws")
+    with pytest.raises(RuntimeError, match="holds the accelerator"):
+        _run(sess, ids, "shard",
+             dist=DistOptions(n_workers=1, kernel="jax", transport="process"))
+    sess.close()
+
+
+def test_jax_kernel_worker_process(tmp_path):
+    """One device-kernel worker process, spawned by a coordinator that
+    holds no accelerator, merges within float tolerance of the stream
+    engine and reports the backend it dispatched to."""
+    from repro.dist.transport import holds_accelerator
+
+    assert not holds_accelerator()  # CPU-only test host
+    sess, ids = _workspace(tmp_path, "ws")
+    _run(sess, ids, "ref", compute="stream")
+    r = _run(sess, ids, "shard",
+             dist=DistOptions(n_workers=1, kernel="jax", transport="process"))
+    (shard,) = r.stats["shards"]
+    assert shard["attempts"] == 1
+    assert shard["pipeline"]["backend"].startswith("jnp-")
+    ref, got = sess.load("ref"), sess.load("shard")
+    for t in ref:
+        np.testing.assert_allclose(got[t], ref[t], rtol=2e-6, atol=2e-6)
+    sess.close()

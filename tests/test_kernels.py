@@ -27,7 +27,7 @@ def _mk(nb, k, w, dtype, seed=0):
 def _pad_run(fn, x0, D, *extras, **kw):
     from repro.kernels.ops import _pallas_padded
 
-    return _pallas_padded(fn, x0, D, *extras, **kw)
+    return _pallas_padded(fn, x0, D, *extras, interpret=True, **kw)
 
 
 @pytest.mark.parametrize("shape", SHAPES)
@@ -53,6 +53,19 @@ def test_ties_kernel_sweep(shape, k, trim):
     want = ref.ties_apply_ref(x0, D, thresh, 0.9)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("trim", [0.001, 0.3, 0.5, 0.999])
+@pytest.mark.parametrize("dtype", DTYPES)
+def test_ties_thresholds_equal_sorted_rank(trim, dtype):
+    """The bisection threshold is exactly the keep-th largest |Δ|, as a
+    sort finds it — also with repeated values and zeros (bf16 deltas)."""
+    nb, k, w = 3, 4, 1000
+    _, D = _mk(nb, k, w, dtype, seed=9)
+    D = D.at[0, 0, :500].set(0.0).at[1, 2].set(0.25)
+    keep = max(1, int(round(trim * w)))
+    want = np.sort(np.abs(np.asarray(D)), axis=-1)[..., w - keep]
+    np.testing.assert_array_equal(np.asarray(ref.ties_thresholds(D, trim)), want)
 
 
 @pytest.mark.parametrize("shape", SHAPES[:3])
@@ -83,23 +96,23 @@ def test_sketch_kernel_sweep(shape):
     np.testing.assert_allclose(s[:, 2], x.mean(axis=1), rtol=1e-3, atol=1e-6)
 
 
-def test_ops_dispatch_forced_pallas(monkeypatch):
-    """merge_blocks through the forced-Pallas path == jnp path."""
+def test_ops_dispatch_forced_pallas():
+    """merge_blocks through the interpret-mode Pallas path == jnp path."""
     from repro.kernels import ops as kops
 
     nb, k, w = 4, 3, 300
     x0, D = _mk(nb, k, w, "float32")
     masks = np.random.default_rng(0).random((nb, k, w)) < 0.5
+    assert kops.backend(interpret=True) == "pallas-interpret"
+    assert kops.backend() == "jnp-" + jax.default_backend()
     for op, theta, extra in [
         ("avg", {}, {}),
         ("ta", {"lam": 0.3}, {}),
         ("ties", {"trim_frac": 0.4}, {}),
         ("dare", {"density": 0.5}, {"masks": masks}),
     ]:
-        monkeypatch.setenv("REPRO_FORCE_PALLAS", "0")
         a = kops.merge_blocks(op, x0, D, theta, **extra)
-        monkeypatch.setenv("REPRO_FORCE_PALLAS", "1")
-        b = kops.merge_blocks(op, x0, D, theta, **extra)
+        b = kops.merge_blocks(op, x0, D, theta, interpret=True, **extra)
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
 
 

@@ -30,7 +30,8 @@ toks = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0, cfg.vocab_size)
 ref = np.asarray(model.forward(params, toks))
 
 # on a (2, 4) mesh with train rules (shard_map dispatch)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 with shardctx.use_mesh(mesh, shardctx.train_rules(False)):
     got = np.asarray(jax.jit(model.forward)(params, toks))
 np.testing.assert_allclose(got, ref, rtol=2e-4, atol=2e-4)

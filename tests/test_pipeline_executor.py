@@ -71,8 +71,12 @@ def test_pipelined_jax_kernel_matches_stream_within_tolerance(populated):
     cfg = PipelineConfig(window_blocks=4, kernel="jax")
     mp.merge(base, ids, "ties", theta={"trim_frac": 0.3}, budget=0.5,
              compute="stream", sid="jk-s")
-    mp.merge(base, ids, "ties", theta={"trim_frac": 0.3}, budget=0.5,
-             compute="pipelined", sid="jk-p", pipeline=cfg)
+    res = mp.merge(base, ids, "ties", theta={"trim_frac": 0.3}, budget=0.5,
+                   compute="pipelined", sid="jk-p", pipeline=cfg)
+    import jax
+
+    # the run stats name the implementation the kernel dispatched to
+    assert res.stats["pipeline"]["backend"] == "jnp-" + jax.default_backend()
     a, b = mp.load("jk-s"), mp.load("jk-p")
     for k in a:
         np.testing.assert_allclose(a[k], b[k], rtol=2e-6, atol=2e-6)
