@@ -49,6 +49,7 @@ from repro.api.jobs import (
 from repro.api.spec import MergeSpec
 from repro.core import blocks as blk
 from repro.core import cost as cost_model
+from repro.core import trace
 from repro.core.catalog import Catalog
 from repro.api.workspace import WorkspaceOps
 from repro.core.executor import (
@@ -1450,6 +1451,8 @@ class MergeService(WorkspaceOps):
         level_nodes = live_nodes
         if not level_nodes:
             return {}
+        # each node's mergepipe.job span runs from here to its result
+        level_start = trace.now()
 
         pool_spec = (
             BudgetSpec.parse(opts.shared_budget)
@@ -1580,14 +1583,16 @@ class MergeService(WorkspaceOps):
                 ram_readers=ram_readers,
             )
 
-        bp = plan_batch(
-            self.catalog,
-            batch_jobs,
-            block_size=self.block_size,
-            shared_budget_b=pool_b,
-            group_budgets=group_budgets,
-            tier_probe=tier_probe,
-        )
+        # the level's planning, shared by its jobs in equal parts
+        with trace.Recorder().span(trace.PLAN) as planning:
+            bp = plan_batch(
+                self.catalog,
+                batch_jobs,
+                block_size=self.block_size,
+                shared_budget_b=pool_b,
+                group_budgets=group_budgets,
+                tier_probe=tier_probe,
+            )
         # weighted-fair accounting: each tenant group is charged the
         # physical union of its own nodes' selections (what a shared-read
         # window pays on its behalf), split equally when a deduped node
@@ -1666,45 +1671,48 @@ class MergeService(WorkspaceOps):
                     if resume.plan_digest != plan.digest():
                         resume.discard()
                         resume = None
+                rec = trace.Recorder(exec_sid)
                 try:
-                    if sharded:
-                        # scatter this node across shard workers; the
-                        # coordinator mirrors execute_merge's txn
-                        # semantics so every handler below works as-is
-                        from repro.dist.coordinator import run_sharded_merge
-                        from repro.dist.lease import DistOptions
+                    with rec.span(trace.JOB, start=level_start) as job:
+                        rec.adopt(planning, job, 1.0 / len(level_nodes))
+                        if sharded:
+                            # scatter this node across shard workers; the
+                            # coordinator mirrors execute_merge's txn
+                            # semantics so every handler below works as-is
+                            from repro.dist.coordinator import run_sharded_merge
+                            from repro.dist.lease import DistOptions
 
-                        result = run_sharded_merge(
-                            plan,
-                            self.snapshots,
-                            self.catalog,
-                            sid=exec_sid,
-                            txn=self.txn,
-                            options=getattr(opts, "dist", None)
-                            or DistOptions(),
-                            coalesce=opts.coalesce,
-                            verify=getattr(opts, "verify", True),
-                            pipeline=opts.pipeline,
-                            cancel=cancel,
-                            progress=self._node_progress(handles),
-                            resume=resume,
-                        )
-                    else:
-                        result = execute_merge(
-                            plan,
-                            self.snapshots,
-                            self.catalog,
-                            sid=exec_sid,
-                            txn=self.txn,
-                            compute=opts.compute,
-                            coalesce=opts.coalesce,
-                            verify=getattr(opts, "verify", True),
-                            expert_readers=expert_readers,
-                            pipeline=opts.pipeline,
-                            cancel=cancel,
-                            progress=self._node_progress(handles),
-                            resume=resume,
-                        )
+                            result = run_sharded_merge(
+                                plan,
+                                self.snapshots,
+                                self.catalog,
+                                sid=exec_sid,
+                                txn=self.txn,
+                                options=getattr(opts, "dist", None)
+                                or DistOptions(),
+                                coalesce=opts.coalesce,
+                                verify=getattr(opts, "verify", True),
+                                pipeline=opts.pipeline,
+                                cancel=cancel,
+                                progress=self._node_progress(handles),
+                                resume=resume,
+                            )
+                        else:
+                            result = execute_merge(
+                                plan,
+                                self.snapshots,
+                                self.catalog,
+                                sid=exec_sid,
+                                txn=self.txn,
+                                compute=opts.compute,
+                                coalesce=opts.coalesce,
+                                verify=getattr(opts, "verify", True),
+                                expert_readers=expert_readers,
+                                pipeline=opts.pipeline,
+                                cancel=cancel,
+                                progress=self._node_progress(handles),
+                                resume=resume,
+                            )
                 except MergeCancelled as e:
                     dead[id(node)] = e
                     for h in handles:
@@ -1763,6 +1771,7 @@ class MergeService(WorkspaceOps):
                         self.arbiter.refund_global(refund)
                     result.stats["resumed"] = True
                 result.stats["plan"] = pr.stats
+                result.stats["trace"] = rec.finish()
                 node.sid = result.sid
                 node.result = result
         finally:

@@ -9,6 +9,7 @@ Layers (paper section in parens):
     delta_iterator  unified full/delta/adapter streaming       (§5.2)
     operators       AVG / TA / TIES / DARE registry            (§4.1)
     executor        ExecuteMerge streaming engine              (§5, Alg.2)
+    trace           spans and counters of a merge job
     transactions    staging + atomic publish + recovery        (§5.3)
     lineage         explain / audit / verify                   (§2.2)
     naive           stateless O(K) baseline pipeline           (§6.1)

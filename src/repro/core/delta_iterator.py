@@ -37,6 +37,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.core import blocks as blk
+from repro.core import trace
 from repro.core.plan import MergePlan
 from repro.store.tensorstore import ModelReader
 
@@ -286,6 +287,7 @@ class DeltaIterator:
     ) -> Tuple[np.ndarray, List[int], List[str]]:
         """Returns (stacked deltas (K_sel, n) float32, expert indexes,
         expert ids).  Performs expert I/O iff the plan selected the block."""
+        t0 = trace.now()
         deltas: List[np.ndarray] = []
         idxs: List[int] = []
         ids: List[str] = []
@@ -312,10 +314,10 @@ class DeltaIterator:
             idxs.append(ei)
             ids.append(e)
         self._used_experts = ids
-        if deltas:
-            return np.stack(deltas), idxs, ids
-        n = base_block.size
-        return np.zeros((0, n), dtype=np.float32), [], []
+        stacked = (np.stack(deltas) if deltas
+                   else np.zeros((0, base_block.size), dtype=np.float32))
+        trace.count("pull_ns", trace.now() - t0)
+        return stacked, idxs, ids
 
     def used_experts(self) -> List[str]:
         """Experts that contributed to the most recent block (coverage)."""

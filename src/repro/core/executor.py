@@ -34,13 +34,13 @@ from __future__ import annotations
 import dataclasses
 import queue
 import threading
-import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
 from repro.core import blocks as blk
+from repro.core import trace
 from repro.core.catalog import Catalog
 from repro.core.delta_iterator import DeltaIterator
 from repro.core.operators import apply_operator, dare_mask_batch
@@ -276,11 +276,47 @@ def execute_merge(
     match ``plan`` is discarded and the merge restarts from scratch
     (staged blocks computed under a different plan are worthless).
     """
-    t0 = time.time()
+    sid = sid or TransactionManager.new_sid()
+    rec = trace.current()
+    owned = rec is None
+    if owned:
+        rec = trace.Recorder(sid)
+    elif rec.job is None:
+        rec.job = sid
+    with rec.span(trace.EXECUTE) as span:
+        result = _execute(
+            rec, plan, snapshots, catalog, sid, txn, coalesce, compute,
+            validate, enforce_budget, verify, expert_readers, pipeline,
+            cancel, progress, resume,
+        )
+    result.stats["seconds"] = span.seconds
+    if owned:
+        result.stats["trace"] = rec.finish()
+    return result
+
+
+def _execute(
+    rec: trace.Recorder,
+    plan: MergePlan,
+    snapshots: SnapshotStore,
+    catalog: Catalog,
+    sid: str,
+    txn: Optional[TransactionManager],
+    coalesce: bool,
+    compute: str,
+    validate: bool,
+    enforce_budget: bool,
+    verify,
+    expert_readers: Optional[Dict[str, object]],
+    pipeline: Optional[PipelineConfig],
+    cancel: Optional[threading.Event],
+    progress: Optional[ProgressFn],
+    resume: Optional[ResumeState],
+) -> MergeResult:
+    """The body of :func:`execute_merge`, inside its span."""
     stats: IOStats = snapshots.stats
     expert_read_before = stats.c_expert
     txn = txn or TransactionManager(snapshots, catalog)
-    sid = sid or TransactionManager.new_sid()
 
     resumed_from: Dict[str, int] = {}
     if resume is not None:
@@ -328,73 +364,75 @@ def execute_merge(
         if missing:
             raise KeyError(f"injected expert_readers missing {missing}")
 
-    # -- Transaction and staging -----------------------------------------
-    if resume is not None:
-        writer = txn.begin(resume=resume)
-    else:
-        writer = txn.begin(sid=sid, plan=plan)
-    touch: Dict[str, List[int]] = {}
-    coverage_rows: List[Tuple[str, int, str]] = []
-
-    base_reader = snapshots.models.open_model(plan.base_id)
-    packed_layout = None
-    if expert_readers is None:
-        if getattr(plan, "layout_id", None):
-            # packed physical layout: one opened layout serves every
-            # expert — each unique extent is read once and fanned out to
-            # all (expert, block) consumers, elided blocks cost nothing,
-            # and physical reads are tagged ``expert_packed``.
-            packed_layout = snapshots.packed.open_layout(plan.layout_id)
-            expert_readers = {
-                e: packed_layout.open_member(e) for e in plan.expert_ids
-            }
+    # readers, verifiers and the transaction: everything before the engine
+    with rec.span(trace.OPEN):
+        # -- Transaction and staging -----------------------------------------
+        if resume is not None:
+            writer = txn.begin(resume=resume)
         else:
-            expert_readers = {
-                e: snapshots.models.open_model(e) for e in plan.expert_ids
-            }
-    # layouts serving this merge (owned or injected): extent re-reads they
-    # record under memory-cap pressure widen the budget slack below
-    merge_layouts = (
-        [packed_layout] if packed_layout is not None
-        else _packed_layouts_behind(expert_readers)
-    )
-    reread_before = sum(l.reread_bytes for l in merge_layouts)
-    # tiered (remote-backed) readers serving this merge: a disk-cache
-    # extent evicted between plan and read is honestly re-fetched from
-    # remote — those bytes widen the budget slack, mirroring packed
-    # extent re-reads under memory-cap pressure
-    tiered_readers = _tiered_readers_behind(
-        [base_reader, *expert_readers.values()]
-    )
-    evict_refetch_before = sum(r.evict_refetch_bytes for r in tiered_readers)
-    # -- verify-on-read (repro.store.integrity) --------------------------
-    # attach a catalog-hash verifier per reader (packed members instead
-    # toggle their layout's extent self-check); a disabled policy
-    # explicitly detaches, so injected readers reused across windows
-    # honor this window's knob
-    verify_policy = VerifyPolicy.coerce(verify)
-    verifiers = []
-    for mid, r in [(plan.base_id, base_reader), *expert_readers.items()]:
-        v = attach_verifier(r, catalog, mid, plan.block_size, verify_policy)
-        if v is not None:
-            verifiers.append(v)
-    # read-repair traffic (corrupt cache extents refilled, corrupt packed
-    # extents served from flat sources) widens budget slack below — the
-    # plan could not have priced corruption in
-    repair_before = sum(
-        getattr(r, "repair_bytes", 0) for r in tiered_readers
-    ) + sum(getattr(l, "repair_bytes", 0) for l in merge_layouts)
-    if compute == "pipelined" and pipeline is None:
-        pipeline = (
-            PipelineConfig.for_remote()
-            if any(
-                getattr(r, "prefers_deep_prefetch", False)
-                for r in tiered_readers
-            )
-            else PipelineConfig()
+            writer = txn.begin(sid=sid, plan=plan)
+        touch: Dict[str, List[int]] = {}
+        coverage_rows: List[Tuple[str, int, str]] = []
+
+        base_reader = snapshots.models.open_model(plan.base_id)
+        packed_layout = None
+        if expert_readers is None:
+            if getattr(plan, "layout_id", None):
+                # packed physical layout: one opened layout serves every
+                # expert — each unique extent is read once and fanned out to
+                # all (expert, block) consumers, elided blocks cost nothing,
+                # and physical reads are tagged ``expert_packed``.
+                packed_layout = snapshots.packed.open_layout(plan.layout_id)
+                expert_readers = {
+                    e: packed_layout.open_member(e) for e in plan.expert_ids
+                }
+            else:
+                expert_readers = {
+                    e: snapshots.models.open_model(e) for e in plan.expert_ids
+                }
+        # layouts serving this merge (owned or injected): extent re-reads they
+        # record under memory-cap pressure widen the budget slack below
+        merge_layouts = (
+            [packed_layout] if packed_layout is not None
+            else _packed_layouts_behind(expert_readers)
         )
-    if compute == "pipelined" and pipeline.kernel == "jax" and kernel_ops is None:
-        from repro.kernels import ops as kernel_ops  # lazy: jax import
+        reread_before = sum(l.reread_bytes for l in merge_layouts)
+        # tiered (remote-backed) readers serving this merge: a disk-cache
+        # extent evicted between plan and read is honestly re-fetched from
+        # remote — those bytes widen the budget slack, mirroring packed
+        # extent re-reads under memory-cap pressure
+        tiered_readers = _tiered_readers_behind(
+            [base_reader, *expert_readers.values()]
+        )
+        evict_refetch_before = sum(r.evict_refetch_bytes for r in tiered_readers)
+        # -- verify-on-read (repro.store.integrity) --------------------------
+        # attach a catalog-hash verifier per reader (packed members instead
+        # toggle their layout's extent self-check); a disabled policy
+        # explicitly detaches, so injected readers reused across windows
+        # honor this window's knob
+        verify_policy = VerifyPolicy.coerce(verify)
+        verifiers = []
+        for mid, r in [(plan.base_id, base_reader), *expert_readers.items()]:
+            v = attach_verifier(r, catalog, mid, plan.block_size, verify_policy)
+            if v is not None:
+                verifiers.append(v)
+        # read-repair traffic (corrupt cache extents refilled, corrupt packed
+        # extents served from flat sources) widens budget slack below — the
+        # plan could not have priced corruption in
+        repair_before = sum(
+            getattr(r, "repair_bytes", 0) for r in tiered_readers
+        ) + sum(getattr(l, "repair_bytes", 0) for l in merge_layouts)
+        if compute == "pipelined" and pipeline is None:
+            pipeline = (
+                PipelineConfig.for_remote()
+                if any(
+                    getattr(r, "prefers_deep_prefetch", False)
+                    for r in tiered_readers
+                )
+                else PipelineConfig()
+            )
+        if compute == "pipelined" and pipeline.kernel == "jax" and kernel_ops is None:
+            from repro.kernels import ops as kernel_ops  # lazy: jax import
     theta = dict(plan.theta)
     seed = int(theta.get("seed", 0))
     is_dare = plan.op.lower() == "dare"
@@ -417,7 +455,7 @@ def execute_merge(
                 is_dare, pipeline, kernel_ops, coalesce, touch, coverage_rows,
                 cancel=cancel, progress=progress,
                 progress_total=progress_total,
-                resume=resume,
+                resume=resume, recorder=rec,
             )
             realized_expert_blocks, pipe_stats = engine.run()
         else:
@@ -485,81 +523,83 @@ def execute_merge(
                     progress_done += n_blocks
                     progress(progress_done, progress_total)
 
-        # -- (2) Validate and atomically publish --------------------------
-        if validate:
-            writer.validate_hashes()
+        with rec.span(trace.COMMIT):
+            # -- (2) Validate and atomically publish --------------------------
+            if validate:
+                with rec.span(trace.VALIDATE):
+                    writer.validate_hashes()
 
-        realized_expert_bytes = stats.c_expert - expert_read_before
-        if enforce_budget and plan.budget_b >= 0:
-            # Budget soundness (§5.1): realized <= planned <= B, up to the
-            # storage layer's accounting granularity (adapters read factor
-            # tensors, which are far below the planned block bytes).
-            slack = 2 * plan.block_size
-            if merge_layouts:
-                # the planner charges each shared extent once; when a
-                # max_pinned_bytes cap forced an extent to be re-read for
-                # a later consumer, those honestly-recorded bytes are a
-                # memory-cap tradeoff, not a plan violation
-                slack += (
-                    sum(l.reread_bytes for l in merge_layouts) - reread_before
-                )
-            if tiered_readers:
-                # disk-cache extents evicted mid-run are re-fetched from
-                # remote at full price — a cache-pressure tradeoff the
-                # plan could not have foreseen, not a plan violation
-                slack += (
-                    sum(r.evict_refetch_bytes for r in tiered_readers)
-                    - evict_refetch_before
-                )
-            if tiered_readers or merge_layouts:
-                # read-repair refetches (expert_repair) are honest extra
-                # bytes forced by detected corruption, never plannable
-                slack += (
-                    sum(getattr(r, "repair_bytes", 0) for r in tiered_readers)
-                    + sum(getattr(l, "repair_bytes", 0) for l in merge_layouts)
-                    - repair_before
-                )
-            if realized_expert_bytes > plan.c_expert_hat + slack:
-                raise RuntimeError(
-                    f"budget soundness violated: realized expert bytes "
-                    f"{realized_expert_bytes} > planned {plan.c_expert_hat}"
-                )
+            realized_expert_bytes = stats.c_expert - expert_read_before
+            if enforce_budget and plan.budget_b >= 0:
+                # Budget soundness (§5.1): realized <= planned <= B, up to the
+                # storage layer's accounting granularity (adapters read factor
+                # tensors, which are far below the planned block bytes).
+                slack = 2 * plan.block_size
+                if merge_layouts:
+                    # the planner charges each shared extent once; when a
+                    # max_pinned_bytes cap forced an extent to be re-read for
+                    # a later consumer, those honestly-recorded bytes are a
+                    # memory-cap tradeoff, not a plan violation
+                    slack += (
+                        sum(l.reread_bytes for l in merge_layouts) - reread_before
+                    )
+                if tiered_readers:
+                    # disk-cache extents evicted mid-run are re-fetched from
+                    # remote at full price — a cache-pressure tradeoff the
+                    # plan could not have foreseen, not a plan violation
+                    slack += (
+                        sum(r.evict_refetch_bytes for r in tiered_readers)
+                        - evict_refetch_before
+                    )
+                if tiered_readers or merge_layouts:
+                    # read-repair refetches (expert_repair) are honest extra
+                    # bytes forced by detected corruption, never plannable
+                    slack += (
+                        sum(getattr(r, "repair_bytes", 0) for r in tiered_readers)
+                        + sum(getattr(l, "repair_bytes", 0) for l in merge_layouts)
+                        - repair_before
+                    )
+                if realized_expert_bytes > plan.c_expert_hat + slack:
+                    raise RuntimeError(
+                        f"budget soundness violated: realized expert bytes "
+                        f"{realized_expert_bytes} > planned {plan.c_expert_hat}"
+                    )
 
-        manifest = {
-            "sid": sid,
-            "plan_id": plan.plan_id,
-            "base_id": plan.base_id,
-            "expert_ids": plan.expert_ids,
-            "op": plan.op,
-            "theta": {k: v for k, v in theta.items() if not k.startswith("_")},
-            "budget_b": plan.budget_b,
-            "c_expert_hat": plan.c_expert_hat,
-            "c_expert_logical_hat": plan.logical_hat,
-            "c_expert_run": realized_expert_bytes,
-            "plan_digest": plan.digest(),
-            "block_size": plan.block_size,
-            "layout_id": plan.layout_id,
-        }
-        sid = txn.atomic_publish(writer, manifest)
-        manifest["output_root"] = snapshots.manifest(sid)["output_root"]
-        txn.commit_record(sid, manifest)
-        catalog.record_touch_map(
-            sid, {t: _ranges_from_indices(ix) for t, ix in touch.items()}
-        )
-        catalog.record_coverage(sid, coverage_rows)
-        if plan.parent_sids:
-            catalog.record_dag_edges(
-                sid,
-                [
-                    (p, "base" if p == plan.base_id else "expert")
-                    for p in plan.parent_sids
-                ],
+            manifest = {
+                "sid": sid,
+                "plan_id": plan.plan_id,
+                "base_id": plan.base_id,
+                "expert_ids": plan.expert_ids,
+                "op": plan.op,
+                "theta": {k: v for k, v in theta.items() if not k.startswith("_")},
+                "budget_b": plan.budget_b,
+                "c_expert_hat": plan.c_expert_hat,
+                "c_expert_logical_hat": plan.logical_hat,
+                "c_expert_run": realized_expert_bytes,
+                "plan_digest": plan.digest(),
+                "block_size": plan.block_size,
+                "layout_id": plan.layout_id,
+            }
+            sid = txn.atomic_publish(writer, manifest)
+            manifest["output_root"] = snapshots.manifest(sid)["output_root"]
+            txn.commit_record(sid, manifest)
+            catalog.record_touch_map(
+                sid, {t: _ranges_from_indices(ix) for t, ix in touch.items()}
             )
-        # lineage is in the catalog — only now is the journal obsolete
-        # (a crash since publish replays coverage from it at recovery)
-        if writer.journal is not None:
-            writer.journal.remove()
-        txn.commit()
+            catalog.record_coverage(sid, coverage_rows)
+            if plan.parent_sids:
+                catalog.record_dag_edges(
+                    sid,
+                    [
+                        (p, "base" if p == plan.base_id else "expert")
+                        for p in plan.parent_sids
+                    ],
+                )
+            # lineage is in the catalog — only now is the journal obsolete
+            # (a crash since publish replays coverage from it at recovery)
+            if writer.journal is not None:
+                writer.journal.remove()
+            txn.commit()
     except Exception:
         txn.abort()
         raise
@@ -572,7 +612,6 @@ def execute_merge(
                 packed_layout.close()
 
     run_stats = {
-        "seconds": time.time() - t0,
         "c_expert_run": realized_expert_bytes,
         "c_expert_hat": plan.c_expert_hat,
         "realized_expert_blocks": realized_expert_blocks,
@@ -760,6 +799,7 @@ class _PipelineEngine:
         progress_total: int = 0,
         resume: Optional[ResumeState] = None,
         spans: Optional[Dict[str, Tuple[int, int]]] = None,
+        recorder: Optional[trace.Recorder] = None,
     ):
         self.plan = plan
         self.base_reader = base_reader
@@ -789,7 +829,9 @@ class _PipelineEngine:
         self.realized_expert_blocks = 0
         self.gauge = _ResidencyGauge()
         self.windows = 0
-        self.wb = WriteBehindWriter(writer, cfg.write_queue_blocks)
+        self.rec = recorder or trace.Recorder()
+        self.wb = WriteBehindWriter(writer, cfg.write_queue_blocks,
+                                    recorder=self.rec)
         self.pool = ThreadPoolExecutor(
             max_workers=cfg.read_threads, thread_name_prefix="mergepipe-read"
         )
@@ -820,6 +862,10 @@ class _PipelineEngine:
         receives ready-to-apply inputs and only does operator math.
         Multiple windows stage concurrently on the pool (pread readers
         are offset-explicit, block sets are disjoint)."""
+        with self.rec.span(trace.BG_STAGE):
+            return self._stage(task, window)
+
+    def _stage(self, task: _TensorTask, window: List[int]) -> Tuple:
         # prompt failure propagation: a doomed merge (writer thread died)
         # must stop pouring expert reads into staging it will never keep
         self.wb.raise_if_failed()
@@ -936,6 +982,10 @@ class _PipelineEngine:
         pulled: Dict[int, Tuple],
     ) -> None:
         chaos_point("executor:window")
+        rec = self.rec
+        # the window's host work is one span, broken only by device calls
+        host = rec.span(trace.NUMPY if self.kernel_ops is None
+                        else trace.HOST_PREP)
         out: Dict[int, np.ndarray] = {}
         retired: Dict[int, int] = {}
         merged: List[int] = []
@@ -969,7 +1019,7 @@ class _PipelineEngine:
                     base_blocks[b], deltas, self.plan.op, self.theta
                 )
                 self.theta.pop("_masks", None)
-        elif merged:
+        else:
             # jitted wrappers: group by (K_sel, width) like the batched
             # path — but windowed, so stacks stay bounded
             groups: Dict[Tuple[int, int], List[int]] = {}
@@ -977,30 +1027,42 @@ class _PipelineEngine:
                 k_sel = pulled[b][0].shape[0]
                 groups.setdefault((k_sel, base_blocks[b].size), []).append(b)
             for (k_sel, width), idxs in groups.items():
-                x0s = np.stack([base_blocks[b] for b in idxs])
+                stored = base_blocks[idxs[0]].dtype
+                x0s = np.stack([base_blocks[b] for b in idxs]).astype(
+                    np.float32, copy=False)
                 Ds = np.stack([pulled[b][0] for b in idxs])
                 masks = None
                 if self.is_dare:
                     masks = np.stack([pulled[b][3] for b in idxs])
-                outs = self.kernel_ops.merge_blocks(
-                    self.plan.op, np.asarray(x0s, np.float32), Ds,
-                    self.theta, masks=masks,
-                )
-                outs = np.asarray(outs).astype(x0s.dtype)
+                host.close()
+                with rec.span(trace.DEVICE):
+                    # one synchronous round trip: H2D, the kernels, D2H
+                    outs = self.kernel_ops.merge_blocks(
+                        self.plan.op, x0s, Ds, self.theta, masks=masks,
+                    )
+                trace.count("kernel_calls", 1)
+                trace.count("h2d_bytes", x0s.nbytes + Ds.nbytes + (
+                    masks.nbytes if masks is not None else 0))
+                trace.count("d2h_bytes", outs.nbytes)
+                host = rec.span(trace.HOST_PREP)
+                outs = outs.astype(stored)
                 for j, b in enumerate(idxs):
                     out[b] = outs[j]
+        host.close()
 
-        for b in window:
-            self.wb.write_block(task.tensor_id, b, out[b],
-                                experts=experts_csv.get(b))
-            self.gauge.sub(retired[b])  # base + delta slots retired
+        with rec.span(trace.WAIT_WRITE):
+            for b in window:
+                self.wb.write_block(task.tensor_id, b, out[b],
+                                    experts=experts_csv.get(b))
+                self.gauge.sub(retired[b])  # base + delta slots retired
         self.windows += 1
         if self.progress is not None:
             self.progress_done += len(window)
             self.progress(self.progress_done, self.progress_total)
 
     def _finish_tensor(self, task: _TensorTask) -> None:
-        self.wb.finish_tensor(task.tensor_id)
+        with self.rec.span(trace.WAIT_WRITE):
+            self.wb.finish_tensor(task.tensor_id)
         self.touch[task.tensor_id] = task.touched
         if task.D is not None:
             # all of this tensor's windows are computed by the time its
@@ -1011,7 +1073,8 @@ class _PipelineEngine:
     def _consume(self) -> None:
         current: Optional[_TensorTask] = None
         while True:
-            kind, a, window, payload = self.q.get()
+            with self.rec.span(trace.WAIT_INPUT):
+                kind, a, window, payload = self.q.get()
             if kind == "error":
                 raise a
             if kind == "done":
@@ -1023,9 +1086,9 @@ class _PipelineEngine:
                     self._finish_tensor(current)
                 chaos_point("executor:tensor")
                 current = a
-                self.wb.begin_tensor(
-                    current.tensor_id, current.spec.shape, current.spec.dtype
-                )
+                with self.rec.span(trace.WAIT_WRITE):
+                    self.wb.begin_tensor(current.tensor_id, current.spec.shape,
+                                         current.spec.dtype)
                 continue
             # consumer-side cancellation checkpoint: a cancel that fires
             # while the producer is already drained still aborts here
@@ -1042,7 +1105,8 @@ class _PipelineEngine:
         ok = False
         try:
             self._consume()
-            self.wb.flush()
+            with self.rec.span(trace.WAIT_WRITE):
+                self.wb.flush()
             ok = True
         finally:
             self.stop.set()
@@ -1057,14 +1121,10 @@ class _PipelineEngine:
         n_experts = len(self.plan.expert_ids)
         return self.realized_expert_blocks, {
             "windows": self.windows,
-            "window_blocks": self.cfg.window_blocks,
-            "prefetch_windows": self.cfg.prefetch_windows,
-            "read_threads": self.cfg.read_threads,
             "kernel": self.cfg.kernel,
             # the implementation the kernel dispatched to (repro.kernels.ops)
             "backend": (self.kernel_ops.backend()
                         if self.kernel_ops is not None else "numpy"),
-            "coalesce_gap_bytes": self.cfg.coalesce_gap_bytes,
             "peak_resident_blocks": self.gauge.peak,
             "resident_bound": self.cfg.max_resident_blocks(n_experts),
             "peak_write_queue_blocks": self.wb.peak_queued,

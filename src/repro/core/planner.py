@@ -20,12 +20,12 @@ Complexity: O(N_b log N_b) in the number of candidate blocks.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from repro.core import blocks as blk
+from repro.core import trace
 from repro.core.catalog import Catalog
 from repro.core.plan import MergePlan
 
@@ -96,7 +96,7 @@ def plan_merge(
     short-circuits re-billing — pass ``reuse=False`` to re-plan against
     the current cache state.
     """
-    t0 = time.time()
+    t0 = trace.now()
     theta = dict(theta or {})
     expert_ids = list(expert_ids)
     parent_sids = list(parent_sids or [])
@@ -179,7 +179,7 @@ def plan_merge(
                 plan,
                 {
                     "reused": True,
-                    "plan_seconds": time.time() - t0,
+                    "plan_seconds": (trace.now() - t0) / 1e9,
                     "c_expert_hat": plan.c_expert_hat,
                 },
             )
@@ -380,7 +380,7 @@ def plan_merge(
     )
     stats = {
         "reused": False,
-        "plan_seconds": time.time() - t0,
+        "plan_seconds": (trace.now() - t0) / 1e9,
         "candidates": n + len(tensor_fallback),
         "admitted": admitted,
         "skipped_budget": skipped_budget,
@@ -540,7 +540,7 @@ def plan_batch(
     against the shared pool), so pool arbitration never over-admits when
     the cache turns out colder than probed.
     """
-    t0 = time.time()
+    t0 = trace.now()
     jobs = list(jobs)
     budgets: List[Optional[int]] = [j.budget_b for j in jobs]
     decisions: List[Dict[str, Any]] = []
@@ -668,7 +668,7 @@ def plan_batch(
 
     stats = {
         "jobs": len(jobs),
-        "plan_seconds": time.time() - t0,
+        "plan_seconds": (trace.now() - t0) / 1e9,
         "c_expert_hat_sum": sum_bytes,
         "c_expert_hat_union": union_bytes,
         "sharing_factor": (sum_bytes / union_bytes) if union_bytes else 1.0,

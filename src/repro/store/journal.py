@@ -41,6 +41,7 @@ import os
 import shutil
 from typing import Dict, List, Optional, Tuple
 
+from repro.core import trace
 from repro.store.iostats import GLOBAL_STATS, IOStats
 from repro.testing.chaos import chaos_point
 
@@ -77,8 +78,10 @@ class ProgressJournal:
         self._f.write(raw)
         self._since_sync += 1
         if sync or self._since_sync >= self.sync_every:
+            t0 = trace.now()
             self._f.flush()
             os.fsync(self._f.fileno())
+            trace.count("fsync_ns", trace.now() - t0)
             self._since_sync = 0
         self.stats.record_write("journal", len(raw))
 

@@ -27,6 +27,7 @@ from typing import Dict, List, Mapping, Optional
 
 import numpy as np
 
+from repro.core import trace
 from repro.store import dtypes
 from repro.store.iostats import GLOBAL_STATS, IOStats
 from repro.store.journal import ProgressJournal, ResumeState, journal_path
@@ -102,6 +103,8 @@ class StagingWriter:
             self._written = 0
             self._next_block = 0
         self._open_name = tensor_id
+        # write and hash time of the open tensor, counted when it finishes
+        self._write_ns = self._hash_ns = 0
         self.specs[tensor_id] = {
             "shape": list(shape),
             "dtype": dtypes.dtype_name(dtype),
@@ -133,9 +136,13 @@ class StagingWriter:
                 f"got {block_idx}"
             )
         raw = np.ascontiguousarray(block).tobytes()
+        t0 = trace.now()
         self._open_file.write(raw)
+        t1 = trace.now()
         self._open_hash.update(raw)
         h8 = hashlib.blake2b(raw, digest_size=8).hexdigest()
+        self._write_ns += t1 - t0
+        self._hash_ns += trace.now() - t1
         self._block_hashes.append(h8)
         self._written += len(raw)
         self._next_block += 1
@@ -147,6 +154,8 @@ class StagingWriter:
         if tensor_id != self._open_name:
             raise RuntimeError(f"tensor {tensor_id} is not the open tensor")
         self._open_file.close()
+        trace.count("write_ns", self._write_ns)
+        trace.count("hash_ns", self._hash_ns)
         spec = self.specs[tensor_id]
         spec["nbytes"] = self._written
         spec["hash"] = self._open_hash.hexdigest()
@@ -222,8 +231,12 @@ class WriteBehindWriter:
 
     _FLUSH = object()  # queue marker: wake any flush() waiters
 
-    def __init__(self, writer: StagingWriter, max_queued_blocks: int = 64):
+    def __init__(self, writer: StagingWriter, max_queued_blocks: int = 64,
+                 recorder: Optional[trace.Recorder] = None):
         self.writer = writer
+        #: the job whose ``mergepipe.bg.write`` spans (one per tensor) the
+        #: writer thread records; None records nothing
+        self.recorder = recorder
         self._q: "queue.Queue" = queue.Queue(maxsize=max(1, max_queued_blocks))
         self._exc: Optional[BaseException] = None
         self._discard = False
@@ -290,9 +303,12 @@ class WriteBehindWriter:
 
     # -- writer thread ------------------------------------------------------
     def _drain(self) -> None:
+        span = None  # the open tensor's mergepipe.bg.write span
         while True:
             method, args = self._q.get()
             if method is None:
+                if span is not None:
+                    span.close()
                 return
             if method is WriteBehindWriter._FLUSH:
                 self._flushed.set()
@@ -301,7 +317,12 @@ class WriteBehindWriter:
                 continue  # drain without applying; producer will re-raise
             try:
                 chaos_point("writer:drain")
+                if method == "begin_tensor" and self.recorder is not None:
+                    span = self.recorder.span(trace.BG_WRITE)
                 getattr(self.writer, method)(*args)
+                if method == "finish_tensor" and span is not None:
+                    span.close()
+                    span = None
             # broad-except-ok: nothing is swallowed — the error (incl.
             # SimulatedCrash) is parked on self._exc and re-raised on the
             # producer thread at the next enqueue/flush/close via

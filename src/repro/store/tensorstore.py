@@ -27,6 +27,7 @@ from typing import Dict, Iterable, List, Mapping, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.core import blocks as blk
+from repro.core import trace
 from repro.store import dtypes
 from repro.store.iostats import GLOBAL_STATS, IOStats
 
@@ -99,13 +100,19 @@ class BlockReaderMixin:
     ) -> np.ndarray:
         spec = self.specs[tensor_id]
         rng = blk.block_range(spec.nbytes, block_idx, block_size)
+        t0 = trace.now()
         data = self.read_range(tensor_id, rng.offset, rng.nbytes, category)
+        t1 = trace.now()
+        trace.count("read_ns." + category, t1 - t0)
+        trace.count("read_bytes." + category, rng.nbytes)
         v = self.verifier
         if v is not None and block_size == v.block_size:
             data = v.check(
                 self, tensor_id, block_idx, rng.offset, rng.nbytes, data,
                 category,
             )
+            trace.count("verify_ns", trace.now() - t1)
+            trace.count("verify_bytes", rng.nbytes)
         return np.frombuffer(data, dtype=spec.dtype)
 
     def read_blocks_coalesced(
@@ -138,6 +145,10 @@ class BlockReaderMixin:
         )
         out: Dict[int, np.ndarray] = {}
         ri = 0
+        v = self.verifier
+        verifying = v is not None and block_size == v.block_size
+        # summed over the runs, counted once per call: not per block
+        read_ns = read_bytes = verify_ns = 0
         for offset, nbytes in blk.coalesce_ranges(ranges, gap=gap_bytes):
             end = offset + nbytes
             run_ranges = []
@@ -150,6 +161,7 @@ class BlockReaderMixin:
             # pass waste only when present: gap=0 keeps the historical
             # 4-arg call shape (tests/benches wrap read_range to emulate
             # storage profiles and must see an unchanged surface)
+            t0 = trace.now()
             data = (
                 self.read_range(tensor_id, offset, nbytes, category)
                 if waste == 0
@@ -157,11 +169,13 @@ class BlockReaderMixin:
                     tensor_id, offset, nbytes, category, waste_nbytes=waste
                 )
             )
-            v = self.verifier
+            t1 = trace.now()
+            read_ns += t1 - t0
+            read_bytes += nbytes
             for r in run_ranges:
                 lo = r.offset - offset
                 chunk = data[lo : lo + r.nbytes]
-                if v is not None and block_size == v.block_size:
+                if verifying:
                     # verified per logical block, not per physical run:
                     # the contract hashes live on the block grid, and a
                     # repair refetches only the corrupt block's range
@@ -170,11 +184,21 @@ class BlockReaderMixin:
                         chunk, category,
                     )
                 out[r.block_idx] = np.frombuffer(chunk, dtype=spec.dtype)
+            verify_ns += trace.now() - t1
+        if read_bytes:
+            trace.count("read_ns." + category, read_ns)
+            trace.count("read_bytes." + category, read_bytes)
+        if verifying and read_bytes:
+            trace.count("verify_ns", verify_ns)
+            trace.count("verify_bytes", sum(r.nbytes for r in ranges))
         return out
 
     def read_tensor(self, tensor_id: str, category: str) -> np.ndarray:
         spec = self.specs[tensor_id]
+        t0 = trace.now()
         data = self.read_range(tensor_id, 0, spec.nbytes, category)
+        trace.count("read_ns." + category, trace.now() - t0)
+        trace.count("read_bytes." + category, spec.nbytes)
         return np.frombuffer(data, dtype=spec.dtype).reshape(spec.shape)
 
     def close(self) -> None:  # pragma: no cover — overridden where needed
