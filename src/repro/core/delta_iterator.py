@@ -12,6 +12,17 @@ Supported expert kinds (checkpoint meta ``kind``):
                ``<tensor>::lora_A`` (r, in) and    blockwise from the
                ``<tensor>::lora_B`` (out, r);      materialized product
 
+Two ways to pull a block:
+
+* ``pull`` returns the float32 deltas, formed on the host (the stream,
+  batched and numpy-kernel paths, and shard workers);
+* ``pull_stored`` returns the blocks as stored, with a flag per row that
+  says whether the row holds full weights (its delta is the row less the
+  base) or the delta itself — the operands of the device path
+  (``kernels.ops.merge_blocks``), which widens them to float32 and forms
+  the deltas on the device.  Both read the same experts in the same order
+  and give the same float32 deltas.
+
 Physical reads go through the coalescing path by default (adjacent
 selected blocks become one sequential read — beyond-paper optimization;
 set ``coalesce=False`` for the paper-faithful per-block I/O pattern).
@@ -318,6 +329,33 @@ class DeltaIterator:
                    else np.zeros((0, base_block.size), dtype=np.float32))
         trace.count("pull_ns", trace.now() - t0)
         return stacked, idxs, ids
+
+    def pull_stored(
+        self, block_idx: int
+    ) -> Tuple[List[Optional[np.ndarray]], List[bool], List[int], List[str]]:
+        """The selected experts' blocks as stored, for the device path:
+        (rows, full, expert indexes, expert ids) in ``pull``'s order.  A
+        full-kind row is the expert's weights (``full`` True); a delta
+        row, and an adapter row in the base's dtype, is the delta itself;
+        an elided packed row is None, a delta of exactly zero.  Performs
+        the same expert I/O as ``pull``."""
+        t0 = trace.now()
+        rows: List[Optional[np.ndarray]] = []
+        full: List[bool] = []
+        idxs: List[int] = []
+        ids: List[str] = []
+        for ei, e, src in self._sources:
+            x = src.pull(block_idx)
+            if x is None:
+                continue
+            elided = x is ELIDED
+            rows.append(None if elided else x)
+            full.append(not elided and src.kind == "full")
+            idxs.append(ei)
+            ids.append(e)
+        self._used_experts = ids
+        trace.count("pull_ns", trace.now() - t0)
+        return rows, full, idxs, ids
 
     def used_experts(self) -> List[str]:
         """Experts that contributed to the most recent block (coverage)."""

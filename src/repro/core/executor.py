@@ -109,7 +109,13 @@ class PipelineConfig:
                         :mod:`repro.kernels.ops` (compiled Pallas on
                         TPU, jnp elsewhere; the run stats' ``backend``
                         names which) — tolerance-level equivalent to
-                        the numpy kernel.
+                        the numpy kernel.  Its operands stay in the
+                        stored dtype: base and expert blocks cross to
+                        the device as stored (a float64 tensor is cast
+                        to float32 on the host), the deltas and the
+                        float32 math are formed there, and the result
+                        comes back in the stored dtype; the run stats'
+                        ``operand_dtype`` names what crossed.
     """
 
     window_blocks: int = 32
@@ -135,9 +141,10 @@ class PipelineConfig:
     # pulled, upcast, DARE masks generated) window-at-a-time on the
     # prefetch pool, but the operator applies per block — profiling shows
     # per-block working sets stay L2-resident while (NB, K, w) stacks are
-    # memory-bandwidth-bound and *slower* on CPU.  The jax kernel groups
-    # whole windows by (K_sel, width) and calls the jitted wrappers,
-    # where batching does pay (one dispatch per group, Pallas on TPU).
+    # memory-bandwidth-bound and *slower* on CPU.  For the jax kernel the
+    # prefetch pool stacks each window's stored blocks by (K_sel, width)
+    # and the compute thread makes one device call per group, where
+    # batching does pay (one dispatch per group, Pallas on TPU).
 
     def validate(self) -> None:
         if self.window_blocks < 1:
@@ -165,9 +172,9 @@ class PipelineConfig:
         staged window in the producer's hand while it blocks on the full
         window queue, plus ``prefetch_windows`` queued, plus one in
         compute; each window may transiently hold, per block, the base
-        block, K expert cache blocks, and the K pulled delta rows
-        materialized from them (write-behind output is bounded separately
-        by ``write_queue_blocks``)."""
+        block, K expert cache blocks, and the K delta rows (or stacked
+        stored rows) materialized from them (write-behind output is
+        bounded separately by ``write_queue_blocks``)."""
         windows_in_flight = 2 * self.prefetch_windows + 3
         return windows_in_flight * self.window_blocks * (1 + 2 * n_experts)
 
@@ -769,13 +776,13 @@ class _PipelineEngine:
 
     The prefetch stage performs *all* physical input I/O: base blocks and
     the plan-selected expert blocks of each window (via the windowed
-    :class:`DeltaIterator` hooks), over thread-safe pread readers.  The
-    compute stage pulls deltas from the prefetched window cache (zero
-    I/O), groups blocks by (K_sel, width) like the batched path — but
-    windowed, so memory stays bounded — and applies the operator
-    vectorized.  Finished blocks stream to the
-    :class:`~repro.store.snapshot.WriteBehindWriter` so output writes
-    overlap the next window's reads and compute.
+    :class:`DeltaIterator` hooks), over thread-safe pread readers, and
+    prepares the window's operands: float32 deltas for the numpy kernel,
+    or for the jax kernel the stored blocks stacked per (K_sel, width)
+    group like the batched path — but windowed, so memory stays bounded.
+    The compute stage (zero I/O) applies the operator.  Finished blocks
+    stream to the :class:`~repro.store.snapshot.WriteBehindWriter` so
+    output writes overlap the next window's reads and compute.
     """
 
     _DONE = ("done", None, None, None)
@@ -829,6 +836,8 @@ class _PipelineEngine:
         self.realized_expert_blocks = 0
         self.gauge = _ResidencyGauge()
         self.windows = 0
+        #: dtypes the device path's operands crossed in (run stats)
+        self.operand_dtypes: set = set()
         self.rec = recorder or trace.Recorder()
         self.wb = WriteBehindWriter(writer, cfg.write_queue_blocks,
                                     recorder=self.rec)
@@ -857,11 +866,11 @@ class _PipelineEngine:
 
     def _stage_window(self, task: _TensorTask, window: List[int]) -> Tuple:
         """One pool task = the full input side of one window: read the
-        base run, read the plan-selected expert blocks, then pull/upcast
-        the delta stacks and generate DARE masks — so the compute thread
-        receives ready-to-apply inputs and only does operator math.
-        Multiple windows stage concurrently on the pool (pread readers
-        are offset-explicit, block sets are disjoint)."""
+        base run, read the plan-selected expert blocks, then prepare the
+        operands and generate DARE masks — so the compute thread receives
+        ready-to-apply inputs and only does operator math.  Multiple
+        windows stage concurrently on the pool (pread readers are
+        offset-explicit, block sets are disjoint)."""
         with self.rec.span(trace.BG_STAGE):
             return self._stage(task, window)
 
@@ -872,25 +881,80 @@ class _PipelineEngine:
         chaos_point("executor:prefetch")
         base_blocks = self._read_base_window(task.tensor_id, window)
         pulled: Dict[int, Tuple] = {}
+        groups: List[Tuple] = []
         if task.D is not None:
             for si in range(task.D.n_sources):
                 self.gauge.add(task.D.prefetch_source(si, window))
-            density = float(self.theta.get("density", 0.5))
-            for b in window:
-                if b not in task.rev:
-                    continue
-                deltas, eidxs, eids = task.D.pull(b, base_blocks[b])
-                masks = None
-                if self.is_dare and eidxs:
-                    masks = dare_mask_batch(
-                        self.seed, eidxs, task.tensor_id, b,
-                        base_blocks[b].size, density,
-                    )
-                pulled[b] = (deltas, eidxs, eids, masks)
-                self.gauge.add(deltas.shape[0])
-            # expert cache slots are now materialized into delta stacks
+            if self.kernel_ops is None:
+                self._pull_deltas(task, window, base_blocks, pulled)
+            else:
+                groups = self._stack_operands(task, window, base_blocks,
+                                              pulled)
+            # expert cache slots are now materialized into delta rows or
+            # operand stacks
             self.gauge.sub(task.D.release_blocks(window))
-        return base_blocks, pulled
+        return base_blocks, pulled, groups
+
+    def _masks(self, task: _TensorTask, b: int, eidxs: List[int],
+               width: int) -> np.ndarray:
+        return dare_mask_batch(self.seed, eidxs, task.tensor_id, b, width,
+                               float(self.theta.get("density", 0.5)))
+
+    def _pull_deltas(self, task: _TensorTask, window: List[int],
+                     base_blocks: Dict, pulled: Dict[int, Tuple]) -> None:
+        """Host operator inputs: each block's float32 delta rows."""
+        for b in window:
+            if b not in task.rev:
+                continue
+            deltas, eidxs, eids = task.D.pull(b, base_blocks[b])
+            masks = None
+            if self.is_dare and eidxs:
+                masks = self._masks(task, b, eidxs, base_blocks[b].size)
+            pulled[b] = (deltas, eidxs, eids, masks)
+            self.gauge.add(deltas.shape[0])
+
+    def _stack_operands(self, task: _TensorTask, window: List[int],
+                        base_blocks: Dict,
+                        pulled: Dict[int, Tuple]) -> List[Tuple]:
+        """Device operands of one window, one group per (K_sel, width):
+        (blocks, base rows (NB, W), expert rows (NB, K, W), full-weights
+        flags, DARE masks).  Rows keep their stored dtype; rows of
+        differing dtypes stack in float32, which holds each exactly.  An
+        elided row is zeros flagged as a delta.  The flags are one bool
+        where the whole group agrees, else (NB, K).  A merged block's base
+        moves from ``base_blocks`` into its group's base rows."""
+        members: Dict[Tuple[int, int], List[Tuple]] = {}
+        for b in window:
+            if b not in task.rev:
+                continue
+            rows, full, eidxs, eids = task.D.pull_stored(b)
+            pulled[b] = (None, eidxs, eids, None)
+            if rows:
+                key = (len(rows), base_blocks[b].size)
+                members.setdefault(key, []).append((b, rows, full, eidxs))
+        t0 = trace.now()
+        groups = []
+        for (k_sel, width), blocks in members.items():
+            idxs = [m[0] for m in blocks]
+            dtypes = ({r.dtype for m in blocks for r in m[1] if r is not None}
+                      or {base_blocks[idxs[0]].dtype})
+            dtype = dtypes.pop() if len(dtypes) == 1 else np.float32
+            Es = np.empty((len(idxs), k_sel, width), dtype)
+            for j, (_, rows, _, _) in enumerate(blocks):
+                for r, row in enumerate(rows):
+                    Es[j, r] = 0 if row is None else row
+            self.gauge.add(Es.shape[0] * k_sel)
+            full = np.array([m[2] for m in blocks], np.bool_)
+            if full.all() or not full.any():
+                full = full.flat[0]
+            masks = None
+            if self.is_dare:
+                masks = np.stack([self._masks(task, b, eidxs, width)
+                                  for b, _, _, eidxs in blocks])
+            x0s = np.stack([base_blocks.pop(b) for b in idxs])
+            groups.append((idxs, x0s, Es, full, masks))
+        trace.count("pull_ns", trace.now() - t0)
+        return groups
 
     def _produce(self) -> None:
         try:
@@ -979,7 +1043,7 @@ class _PipelineEngine:
     # ------------------------------------------------------------- stage 2
     def _compute_window(
         self, task: _TensorTask, window: List[int], base_blocks: Dict,
-        pulled: Dict[int, Tuple],
+        pulled: Dict[int, Tuple], groups: List[Tuple],
     ) -> None:
         chaos_point("executor:window")
         rec = self.rec
@@ -996,17 +1060,16 @@ class _PipelineEngine:
                 out[b] = base_blocks[b]
                 retired[b] = 1
                 continue
-            deltas, eidxs, eids, _masks = got
+            _, _, eids, _ = got
             self.realized_expert_blocks += len(eids)
             if eids:
                 task.touched.append(b)
                 experts_csv[b] = ",".join(eids)
                 self.coverage_rows.append((task.tensor_id, b, experts_csv[b]))
-            retired[b] = 1 + deltas.shape[0]
-            if deltas.shape[0] == 0:
-                out[b] = base_blocks[b]
-            else:
                 merged.append(b)
+            else:
+                out[b] = base_blocks[b]
+            retired[b] = 1 + len(eids)
 
         if self.kernel_ops is None:
             # per-block numpy apply — bit-identical to the stream path and
@@ -1020,32 +1083,29 @@ class _PipelineEngine:
                 )
                 self.theta.pop("_masks", None)
         else:
-            # jitted wrappers: group by (K_sel, width) like the batched
-            # path — but windowed, so stacks stay bounded
-            groups: Dict[Tuple[int, int], List[int]] = {}
-            for b in merged:
-                k_sel = pulled[b][0].shape[0]
-                groups.setdefault((k_sel, base_blocks[b].size), []).append(b)
-            for (k_sel, width), idxs in groups.items():
-                stored = base_blocks[idxs[0]].dtype
-                x0s = np.stack([base_blocks[b] for b in idxs]).astype(
-                    np.float32, copy=False)
-                Ds = np.stack([pulled[b][0] for b in idxs])
-                masks = None
-                if self.is_dare:
-                    masks = np.stack([pulled[b][3] for b in idxs])
+            # one device call per (K_sel, width) group staged by the pool
+            ops = self.kernel_ops
+            for idxs, x0s, Es, full, masks in groups:
+                theta = dict(self.theta, _subtract=full)
                 host.close()
                 with rec.span(trace.DEVICE):
                     # one synchronous round trip: H2D, the kernels, D2H
-                    outs = self.kernel_ops.merge_blocks(
-                        self.plan.op, x0s, Ds, self.theta, masks=masks,
-                    )
-                trace.count("kernel_calls", 1)
-                trace.count("h2d_bytes", x0s.nbytes + Ds.nbytes + (
-                    masks.nbytes if masks is not None else 0))
-                trace.count("d2h_bytes", outs.nbytes)
+                    outs = ops.merge_blocks(self.plan.op, x0s, Es, theta,
+                                            masks=masks)
                 host = rec.span(trace.HOST_PREP)
-                outs = outs.astype(stored)
+                shipped = [x0s, Es, np.asarray(full)] + (
+                    [masks] if masks is not None else [])
+                trace.count("kernel_calls", 1)
+                trace.count("h2d_bytes", sum(
+                    a.size * ops.operand_dtype(a.dtype).itemsize
+                    for a in shipped))
+                trace.count("d2h_bytes", outs.size * ops.operand_dtype(
+                    outs.dtype).itemsize)
+                trace.count("device_delta_rows", Es.shape[0] * Es.shape[1])
+                self.operand_dtypes.update(
+                    ops.operand_dtype(a.dtype).name for a in (x0s, Es))
+                # merge_blocks returns the stored dtype
+                outs = np.asarray(outs, x0s.dtype)
                 for j, b in enumerate(idxs):
                     out[b] = outs[j]
         host.close()
@@ -1093,8 +1153,7 @@ class _PipelineEngine:
             # consumer-side cancellation checkpoint: a cancel that fires
             # while the producer is already drained still aborts here
             _check_cancel(self.cancel, self.plan.plan_id)
-            base_blocks, pulled = payload
-            self._compute_window(a, window, base_blocks, pulled)
+            self._compute_window(a, window, *payload)
 
     # ------------------------------------------------------------ lifecycle
     def run(self) -> Tuple[int, Dict]:
@@ -1125,6 +1184,7 @@ class _PipelineEngine:
             # the implementation the kernel dispatched to (repro.kernels.ops)
             "backend": (self.kernel_ops.backend()
                         if self.kernel_ops is not None else "numpy"),
+            "operand_dtype": ",".join(sorted(self.operand_dtypes)) or None,
             "peak_resident_blocks": self.gauge.peak,
             "resident_bound": self.cfg.max_resident_blocks(n_experts),
             "peak_write_queue_blocks": self.wb.peak_queued,

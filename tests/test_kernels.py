@@ -24,10 +24,22 @@ def _mk(nb, k, w, dtype, seed=0):
     return x0, D
 
 
-def _pad_run(fn, x0, D, *extras, **kw):
+def _as_stored(x0, D, seed=0):
+    """Expert rows E and flags for deltas D: a flagged row holds full
+    weights x0 + Δ, the others the delta; and the float32 deltas the
+    kernels form from them."""
+    from repro.kernels.ops import _deltas
+
+    full = jnp.asarray(np.random.default_rng(seed).random(D.shape[:2]) < 0.5)
+    E = jnp.where(full[:, :, None], x0[:, None, :] + D, D)
+    return E, full, _deltas(x0, E, full)
+
+
+def _pad_run(fn, x0, E, full, *extras, **kw):
     from repro.kernels.ops import _pallas_padded
 
-    return _pallas_padded(fn, x0, D, *extras, interpret=True, **kw)
+    return _pallas_padded(fn, x0, E, full.astype(jnp.float32), *extras,
+                          interpret=True, **kw)
 
 
 @pytest.mark.parametrize("shape", SHAPES)
@@ -36,7 +48,8 @@ def _pad_run(fn, x0, D, *extras, **kw):
 def test_linear_kernel_sweep(shape, k, dtype):
     nb, w = shape
     x0, D = _mk(nb, k, w, dtype)
-    got = _pad_run(mb.linear_merge_pallas, x0, D, coeff=0.37)
+    E, full, D = _as_stored(x0, D)
+    got = _pad_run(mb.linear_merge_pallas, x0, E, full, coeff=0.37)
     want = x0 + 0.37 * D.sum(axis=1)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
@@ -48,8 +61,9 @@ def test_linear_kernel_sweep(shape, k, dtype):
 def test_ties_kernel_sweep(shape, k, trim):
     nb, w = shape
     x0, D = _mk(nb, k, w, "float32", seed=k)
+    E, full, D = _as_stored(x0, D, seed=k)
     thresh = ref.ties_thresholds(D, trim)
-    got = _pad_run(mb.ties_merge_pallas, x0, D, thresh, lam=0.9)
+    got = _pad_run(mb.ties_merge_pallas, x0, E, full, thresh, lam=0.9)
     want = ref.ties_apply_ref(x0, D, thresh, 0.9)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
@@ -76,7 +90,8 @@ def test_dare_kernel_sweep(shape, k, density):
     x0, D = _mk(nb, k, w, "float32", seed=k + 1)
     rng = np.random.default_rng(7)
     masks = jnp.asarray(rng.random((nb, k, w)) < density)
-    got = _pad_run(mb.dare_merge_pallas, x0, D, masks,
+    E, full, D = _as_stored(x0, D, seed=k + 1)
+    got = _pad_run(mb.dare_merge_pallas, x0, E, full, masks,
                    density=density, lam=1.1)
     want = ref.dare_ref(x0, D, masks, density, 1.1)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -114,6 +129,43 @@ def test_ops_dispatch_forced_pallas():
         a = kops.merge_blocks(op, x0, D, theta, **extra)
         b = kops.merge_blocks(op, x0, D, theta, interpret=True, **extra)
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("flags", ["per_row", "one"])
+@pytest.mark.parametrize("interpret", [False, True], ids=["jnp", "pallas"])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float16", "float32",
+                                   "float64"])
+@pytest.mark.parametrize("op,theta", [("avg", {}), ("ta", {"lam": 0.3}),
+                                      ("ties", {"trim_frac": 0.2}),
+                                      ("dare", {"density": 0.5})])
+def test_stored_operands_give_the_float32_path_bytes(op, theta, dtype,
+                                                     interpret, flags):
+    """Stored-dtype operands with full-weights flags give the bytes of the
+    float32 path: deltas formed and widened on the host, float32 operands,
+    the result rounded on the host."""
+    from repro.kernels import ops as kops
+    from repro.store.dtypes import to_np_dtype
+
+    dt = to_np_dtype(dtype)
+    nb, k, w = 5, 3, 700  # ragged in both tile dimensions
+    rng = np.random.default_rng(11)
+    x0 = (0.02 * rng.normal(size=(nb, w))).astype(dt)
+    E = (x0.astype(np.float32)[:, None, :]
+         + 4e-4 * rng.normal(size=(nb, k, w))).astype(dt)
+    full = rng.random((nb, k)) < 0.5 if flags == "per_row" else np.True_
+    full_rows = np.broadcast_to(full, (nb, k))
+    E[~full_rows] = (4e-4 * rng.normal(size=(int((~full_rows).sum()), w))
+                     ).astype(dt)
+    x0f = x0.astype(np.float32)
+    D = np.where(full_rows[:, :, None], E.astype(np.float32) - x0f[:, None, :],
+                 E.astype(np.float32))
+    extra = ({"masks": rng.random((nb, k, w)) < 0.5} if op == "dare" else {})
+    want = kops.merge_blocks(op, x0f, D, theta, interpret=interpret,
+                             **extra).astype(dt)
+    got = kops.merge_blocks(op, x0, E, dict(theta, _subtract=full),
+                            interpret=interpret, **extra)
+    assert got.dtype == dt
+    assert got.tobytes() == want.tobytes()
 
 
 # ------------------------------------------------------ flash attention

@@ -111,6 +111,111 @@ def test_pipelined_expert_kinds(workspace, op, theta):
     assert _tensor_hashes(mp, "kinds-s") == _tensor_hashes(mp, "kinds-p")
 
 
+def _kinds_fleet(mp, dtype):
+    """Base and experts of every kind in ``dtype``: ``full`` and
+    ``frozen`` store weights (``frozen``'s "w" equals the base), ``delta``
+    and ``zero`` store deltas (``zero``'s "v" is all zeros), ``adapter``
+    LoRA factors.  "v" ends in a ragged block."""
+    rng = np.random.default_rng(4)
+    base = {"w": rng.normal(size=(96, 64)), "v": rng.normal(size=(3000,))}
+    delta = {k: 0.05 * rng.normal(size=v.shape) for k, v in base.items()}
+    cast = lambda d: {k: np.asarray(v).astype(dtype) for k, v in d.items()}
+    mp.register_model("base", cast(base))
+    mp.register_model("full", cast({k: base[k] + delta[k] for k in base}))
+    mp.register_model("frozen", cast({"w": base["w"],
+                                      "v": base["v"] - delta["v"]}))
+    mp.register_model("delta", cast(delta), kind="delta")
+    mp.register_model("zero", cast({"w": -delta["w"],
+                                    "v": np.zeros_like(base["v"])}),
+                      kind="delta")
+    mp.register_model("adapter", {
+        "w::lora_A": rng.normal(size=(4, 64)).astype(np.float32),
+        "w::lora_B": rng.normal(size=(96, 4)).astype(np.float32)},
+        kind="adapter", scale=0.1)
+
+
+#: expert sets: one kind each; elided packed rows among full and delta
+#: rows; every kind in one window
+EXPERT_SETS = {
+    "full": ["full"], "delta": ["delta"], "adapter": ["adapter"],
+    "elided": ["frozen", "zero", "full"],
+    "mixed": ["full", "delta", "adapter", "frozen"],
+}
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float16", "float32"])
+@pytest.mark.parametrize("experts", sorted(EXPERT_SETS))
+@pytest.mark.parametrize("op,theta", OPS)
+def test_stored_operands_bit_identical_to_float32_path(tmp_path, op, theta,
+                                                       experts, dtype):
+    """The device path ships stored-dtype blocks and forms the deltas on
+    the device; its snapshot equals, byte for byte, the float32 path's
+    (``compute="batched"``: deltas widened on the host, float32 operands,
+    the result rounded on the host)."""
+    from repro.store.dtypes import to_np_dtype
+
+    mp = MergePipe(str(tmp_path), block_size=4096, stats=IOStats())
+    _kinds_fleet(mp, to_np_dtype(dtype))
+    ids = EXPERT_SETS[experts]
+    if experts == "elided":
+        rep = mp.repack(ids, "base", layout_id="L")
+        assert rep["elided_blocks"] > 0
+    mp.merge("base", ids, op, theta=theta, budget=None, compute="batched",
+             sid="f32")
+    res = mp.merge("base", ids, op, theta=theta, budget=None,
+                   compute="pipelined", sid="stored",
+                   pipeline=PipelineConfig(window_blocks=4, kernel="jax"))
+    if experts == "elided":
+        assert res.manifest["layout_id"] == "L"
+    assert res.stats["pipeline"]["operand_dtype"] == dtype
+    a, b = mp.load("f32"), mp.load("stored")
+    for k in a:
+        assert b[k].dtype == a[k].dtype == to_np_dtype(dtype)
+        assert b[k].tobytes() == a[k].tobytes(), k
+    assert _tensor_hashes(mp, "f32") == _tensor_hashes(mp, "stored")
+    mp.close()
+
+
+def test_device_path_counts_stored_operands(tmp_path):
+    """h2d_bytes counts the stored-dtype operands shipped, and
+    device_delta_rows the expert rows whose deltas the device formed;
+    the residency bound holds; the numpy kernel counts neither."""
+    from repro.store.dtypes import bfloat16
+
+    mp = MergePipe(str(tmp_path), block_size=4096, stats=IOStats())
+    base, experts = make_models(shapes={"w": (96, 64), "v": (3000,)})
+    mp.register_model("base", {k: v.astype(bfloat16) for k, v in base.items()})
+    ids = []
+    for i, e in enumerate(experts):
+        mp.register_model(f"e{i}", {k: v.astype(bfloat16) for k, v in e.items()})
+        ids.append(f"e{i}")
+    runs = {}
+    for kernel in ("jax", "numpy"):
+        runs[kernel] = mp.merge(
+            "base", ids, "ta", budget=None, compute="pipelined",
+            pipeline=PipelineConfig(window_blocks=4, prefetch_windows=1,
+                                    read_threads=2, kernel=kernel))
+    res = runs["jax"]
+    counters = res.stats["trace"]["counters"]
+    pipe = res.stats["pipeline"]
+    model_bytes = sum(v.size * 2 for v in base.values())
+    # every block merged: base + 3 experts in bf16, one flag per call
+    assert counters["h2d_bytes"] == (
+        4 * model_bytes + counters["kernel_calls"])
+    assert counters["d2h_bytes"] == model_bytes
+    assert counters["device_delta_rows"] == res.stats[
+        "realized_expert_blocks"] > 0
+    assert pipe["operand_dtype"] == "bfloat16"
+    assert pipe["peak_resident_blocks"] <= pipe["resident_bound"]
+    numpy_run = runs["numpy"]
+    for key in ("h2d_bytes", "device_delta_rows", "kernel_calls"):
+        assert key not in numpy_run.stats["trace"]["counters"]
+    assert numpy_run.stats["pipeline"]["operand_dtype"] is None
+    assert numpy_run.stats["realized_expert_blocks"] == res.stats[
+        "realized_expert_blocks"]
+    mp.close()
+
+
 def test_pipelined_int_passthrough_and_coalesce_off(workspace):
     mp = workspace
     base = {"w": np.ones((2048,), np.float32),

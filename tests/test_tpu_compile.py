@@ -3,8 +3,9 @@
 The TPU compiler is installed with JAX and compiles for a chip that is
 described, not attached.  These compiles refuse what interpret mode
 cannot see (tiles not aligned to the layout, too much fast memory, a
-program too large for the device) at the sizes ``chip_smoke.py`` runs:
-windows of 32 blocks of 65,536 bf16 elements, upcast to float32.
+program too large for the device) at the sizes the chip benchmark runs:
+windows of 32 blocks of 65,536 bf16 elements, shipped in bf16 and
+widened inside the program.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
@@ -47,8 +48,8 @@ def one_chip():
     compilation_cache.reset_cache()
 
 
-def _f32(sharding, *shape):
-    return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)
+def _arg(sharding, dtype, *shape):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
 def _compile(fn, *args):
@@ -60,30 +61,43 @@ def _compile(fn, *args):
     return compiled.as_text()
 
 
+def _merge(sharding, op, params, k, dtype=jnp.bfloat16, full=(),
+           masks=False):
+    """Compile the whole program of one ``merge_blocks`` call on a
+    window: stored-dtype operands and the full-weights flags."""
+    return _compile(
+        ops._merge, _arg(sharding, dtype, NB, W), _arg(sharding, dtype, NB, k, W),
+        _arg(sharding, np.bool_, *full),
+        _arg(sharding, np.bool_, NB, k, W) if masks else None,
+        op, params, True, False)
+
+
 @pytest.mark.parametrize("k", [4, 8])
 def test_linear_kernel_compiles_for_v5e(one_chip, k):
-    hlo = _compile(ops._linear_pallas, _f32(one_chip, NB, W),
-                   _f32(one_chip, NB, k, W), 1.0 / (k + 1), False)
+    hlo = _merge(one_chip, "ta", (0.3,), k)
     assert "tpu_custom_call" in hlo
 
 
 @pytest.mark.parametrize("k", [4, 8])
 def test_ties_kernel_compiles_for_v5e(one_chip, k):
-    hlo = _compile(ops._ties_pallas, _f32(one_chip, NB, W),
-                   _f32(one_chip, NB, k, W), _f32(one_chip, NB, k), 1.0, False)
+    hlo = _merge(one_chip, "ties", (0.2, 1.0), k)
     assert "tpu_custom_call" in hlo
 
 
 @pytest.mark.parametrize("k", [4, 8])
 def test_ties_thresholds_compile_for_v5e(one_chip, k):
-    hlo = _compile(ops._ties_thresh_jit, _f32(one_chip, NB, k, W), 0.3)
+    hlo = _merge(one_chip, "ties", (0.3, 1.0), k, full=(NB, k))
     # a sort of 65,536-wide rows costs ~20 s of compile for each shape
     assert "sort" not in hlo
 
 
 def test_dare_kernel_compiles_for_v5e(one_chip):
-    k = 4
-    masks = jax.ShapeDtypeStruct((NB, k, W), np.bool_, sharding=one_chip)
-    hlo = _compile(ops._dare_pallas, _f32(one_chip, NB, W),
-                   _f32(one_chip, NB, k, W), masks, 0.5, 1.0, False)
+    hlo = _merge(one_chip, "dare", (0.5, 1.0), 4, masks=True)
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.float16])
+def test_other_stored_dtypes_compile_for_v5e(one_chip, dtype):
+    # float32: the batched path's operands; per-row flags of a mixed window
+    hlo = _merge(one_chip, "avg", (1 / 5,), 4, dtype=dtype, full=(NB, 4))
     assert "tpu_custom_call" in hlo

@@ -130,7 +130,9 @@ def test_h2d_bytes_are_the_operands_handed_to_the_kernel(
     merge_blocks = ops.merge_blocks
 
     def counting(op_, x0s, Ds, theta_, masks=None):
-        moved["in"] += x0s.nbytes + Ds.nbytes + (
+        # the operands as stored, their full-weights flags, DARE's masks
+        moved["in"] += x0s.nbytes + Ds.nbytes + np.asarray(
+            theta_["_subtract"]).nbytes + (
             masks.nbytes if masks is not None else 0)
         out = merge_blocks(op_, x0s, Ds, theta_, masks=masks)
         moved["out"] += out.nbytes
