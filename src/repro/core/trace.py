@@ -46,9 +46,12 @@ NUMPY = "mergepipe.exec.numpy"
 WAIT_WRITE = "mergepipe.exec.wait_write"
 COMMIT = "mergepipe.commit"
 VALIDATE = "mergepipe.commit.validate"
-# background threads: one span per staged window, one per written tensor
+# background threads: one span per staged window, one per written tensor,
+# and inside the latter the tensor's begin and finish (file create, the
+# journal's synced records, close)
 BG_STAGE = "mergepipe.bg.stage"
 BG_WRITE = "mergepipe.bg.write"
+BG_TENSOR_SYNC = "mergepipe.bg.tensor_sync"
 
 #: finished jobs whose sums :func:`recent` keeps
 RECENT_JOBS = 256

@@ -234,8 +234,10 @@ class WriteBehindWriter:
     def __init__(self, writer: StagingWriter, max_queued_blocks: int = 64,
                  recorder: Optional[trace.Recorder] = None):
         self.writer = writer
-        #: the job whose ``mergepipe.bg.write`` spans (one per tensor) the
-        #: writer thread records; None records nothing
+        #: the job whose ``mergepipe.bg.write`` spans (one per tensor, each
+        #: holding a ``mergepipe.bg.tensor_sync`` span for the tensor's
+        #: begin and one for its finish) the writer thread records; None
+        #: records nothing
         self.recorder = recorder
         self._q: "queue.Queue" = queue.Queue(maxsize=max(1, max_queued_blocks))
         self._exc: Optional[BaseException] = None
@@ -317,9 +319,13 @@ class WriteBehindWriter:
                 continue  # drain without applying; producer will re-raise
             try:
                 chaos_point("writer:drain")
-                if method == "begin_tensor" and self.recorder is not None:
+                if method == "write_block" or self.recorder is None:
+                    getattr(self.writer, method)(*args)
+                    continue
+                if method == "begin_tensor":
                     span = self.recorder.span(trace.BG_WRITE)
-                getattr(self.writer, method)(*args)
+                with self.recorder.span(trace.BG_TENSOR_SYNC):
+                    getattr(self.writer, method)(*args)
                 if method == "finish_tensor" and span is not None:
                     span.close()
                     span = None

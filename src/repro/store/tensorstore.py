@@ -13,11 +13,16 @@ only those bytes — and **concurrent readers never race**: ``pread`` takes
 an explicit offset and does not touch the shared file position, so the
 pipelined executor's prefetch pool (and v2 batch sessions sharing a
 ``CachingModelReader``) can read the same tensor from many threads.
+A reader keeps at most :data:`MAX_OPEN_TENSOR_FILES` tensor files open,
+least recently used closed first, so a merge of a many-tensor
+checkpoint (a mixture of experts holds hundreds of tensors per layer)
+holds O(models) descriptors, not O(models x tensors).
 Every physical read/write is tagged into :mod:`repro.store.iostats` with
 the paper's cost category.
 """
 from __future__ import annotations
 
+import collections
 import hashlib
 import json
 import os
@@ -36,6 +41,11 @@ TENSOR_DIR = "tensors"
 #: presence of this stub (instead of MODEL.json) marks a model whose
 #: bytes live in a remote object store (see repro.store.remote)
 REMOTE_STUB = "REMOTE.json"
+#: tensor files one ModelReader keeps open; more may be open for a moment
+#: while preads pin them.  Windows stage in tensor order and at most a few
+#: tensors at once (``PipelineConfig.prefetch_windows`` + 1 on the read
+#: pool), so a merge opens each tensor file about once per model.
+MAX_OPEN_TENSOR_FILES = 8
 
 
 def _hash_bytes(data: bytes) -> str:
@@ -228,20 +238,44 @@ class ModelReader(BlockReaderMixin):
         self.specs: Dict[str, TensorSpec] = {
             name: TensorSpec(spec) for name, spec in doc["tensors"].items()
         }
-        self._fds: Dict[str, int] = {}
+        #: tensor -> [fd, preads using it], least recently used first
+        self._fds = collections.OrderedDict()  # guarded-by: _fd_lock
         self._fd_lock = threading.Lock()
 
     # -- physical reads ----------------------------------------------------
-    def _fd(self, tensor_id: str) -> int:
-        fd = self._fds.get(tensor_id)
-        if fd is None:
-            with self._fd_lock:
-                fd = self._fds.get(tensor_id)
-                if fd is None:
-                    path = os.path.join(self.dir, self.specs[tensor_id].file)
-                    fd = os.open(path, os.O_RDONLY)
-                    self._fds[tensor_id] = fd
-        return fd
+    def _pin(self, tensor_id: str) -> List[int]:
+        """The tensor's open ``[fd, pins]``, pinned against closing until
+        :meth:`_unpin`: a descriptor closed under a running ``pread`` could
+        be reused by the next open and serve another tensor's bytes."""
+        with self._fd_lock:
+            ent = self._fds.get(tensor_id)
+            if ent is None:
+                path = os.path.join(self.dir, self.specs[tensor_id].file)
+                ent = self._fds[tensor_id] = [os.open(path, os.O_RDONLY), 0]
+                trace.count("fd_opens", 1)
+            else:
+                self._fds.move_to_end(tensor_id)
+            ent[1] += 1
+            self._close_idle()
+            return ent
+
+    def _unpin(self, ent: List[int]) -> None:
+        with self._fd_lock:
+            ent[1] -= 1
+            self._close_idle()
+
+    def _close_idle(self) -> None:  # unguarded-ok: caller holds _fd_lock
+        """Close least recently used unpinned files beyond the cap."""
+        excess = len(self._fds) - MAX_OPEN_TENSOR_FILES
+        if excess <= 0:
+            return
+        for tensor_id, (fd, pins) in list(self._fds.items()):
+            if not pins:
+                del self._fds[tensor_id]
+                os.close(fd)
+                excess -= 1
+                if not excess:
+                    return
 
     def read_range(
         self,
@@ -260,15 +294,18 @@ class ModelReader(BlockReaderMixin):
         budget categories count payload bytes only while total physical
         volume stays fully accounted.
         """
-        fd = self._fd(tensor_id)
+        ent = self._pin(tensor_id)
         chunks = []
         got = 0
-        while got < nbytes:  # pread may return short on signals / EOF
-            chunk = os.pread(fd, nbytes - got, offset + got)
-            if not chunk:
-                break
-            chunks.append(chunk)
-            got += len(chunk)
+        try:
+            while got < nbytes:  # pread may return short on signals / EOF
+                chunk = os.pread(ent[0], nbytes - got, offset + got)
+                if not chunk:
+                    break
+                chunks.append(chunk)
+                got += len(chunk)
+        finally:
+            self._unpin(ent)
         data = chunks[0] if len(chunks) == 1 else b"".join(chunks)
         if len(data) != nbytes:
             raise IOError(
@@ -282,7 +319,7 @@ class ModelReader(BlockReaderMixin):
 
     def close(self) -> None:
         with self._fd_lock:
-            for fd in self._fds.values():
+            for fd, _ in self._fds.values():
                 os.close(fd)
             self._fds.clear()
 

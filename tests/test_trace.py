@@ -179,6 +179,26 @@ def test_no_span_per_block(tmp_path, window_blocks):
         assert counts[name] == 1, name
 
 
+def test_tensor_files_opened_and_tensor_syncs(tmp_path, recorders):
+    n_tensors, k = 4, 3
+    sess, ids = _fleet(tmp_path, n_tensors=n_tensors, k=k)
+    try:
+        res = _merge(sess, ids, "jax")
+    finally:
+        sess.close()
+    tr = res.stats["trace"]
+    # each tensor file of the base and of every expert, opened once
+    assert tr["counters"]["fd_opens"] == n_tensors * (k + 1)
+    # on the writer thread, each tensor's begin and its finish
+    syncs = [sp for sp in recorders[-1].spans()
+             if sp.name == trace.BG_TENSOR_SYNC]
+    assert len(syncs) == tr["spans"][trace.BG_TENSOR_SYNC]["count"] == (
+        2 * n_tensors)
+    for sp in syncs:
+        assert sp.parent.name == trace.BG_WRITE
+        assert sp.parent.start <= sp.start <= sp.end <= sp.parent.end
+
+
 def test_self_time_counters_and_recent():
     rec = trace.Recorder("job-a")
     with rec.span(trace.EXECUTE) as outer:
