@@ -49,7 +49,9 @@ from repro.core.transactions import TransactionManager
 from repro.store.integrity import VerifyPolicy, attach_verifier
 from repro.store.iostats import IOStats
 from repro.store.journal import ResumeState
-from repro.store.snapshot import SnapshotStore, WriteBehindWriter
+from repro.store.snapshot import (
+    HASH_LANE_BLOCKS, SnapshotStore, WriteBehindWriter,
+)
 from repro.testing.chaos import chaos_point
 
 
@@ -94,7 +96,9 @@ class PipelineConfig:
                         (prefetch depth; back-pressure beyond this).
     read_threads      — thread-pool size for base/expert block reads
                         (pread-based readers, safe under concurrency).
-    write_queue_blocks — bound on output blocks queued behind compute.
+    write_queue_blocks — bound on output blocks queued behind compute
+                        (the writer's hash lanes hold at most
+                        ``HASH_LANE_BLOCKS`` written blocks more).
     coalesce_gap_bytes — tolerated unselected bytes between two selected
                         ranges before a coalesced read is split (0 =
                         merge only strictly adjacent ranges).  On
@@ -174,7 +178,8 @@ class PipelineConfig:
         compute; each window may transiently hold, per block, the base
         block, K expert cache blocks, and the K delta rows (or stacked
         stored rows) materialized from them (write-behind output is
-        bounded separately by ``write_queue_blocks``)."""
+        bounded separately by ``write_queue_blocks`` plus
+        ``HASH_LANE_BLOCKS``)."""
         windows_in_flight = 2 * self.prefetch_windows + 3
         return windows_in_flight * self.window_blocks * (1 + 2 * n_experts)
 
@@ -1189,4 +1194,7 @@ class _PipelineEngine:
             "resident_bound": self.cfg.max_resident_blocks(n_experts),
             "peak_write_queue_blocks": self.wb.peak_queued,
             "write_queue_bound": self.cfg.write_queue_blocks,
+            # written blocks the writer's hash lanes held, beside the queue
+            "peak_hash_lane_blocks": self.wb.writer.peak_hash_lane_blocks,
+            "hash_lane_bound": HASH_LANE_BLOCKS,
         }

@@ -91,6 +91,10 @@ class ShardRegionWriter:
     def validate_hashes(self) -> None:
         self.inner.validate_hashes()
 
+    @property
+    def peak_hash_lane_blocks(self) -> int:
+        return self.inner.peak_hash_lane_blocks
+
     def abort(self) -> None:
         self.inner.abort()
 

@@ -15,6 +15,7 @@ Layout under the workspace root:
 """
 from __future__ import annotations
 
+import collections
 import hashlib
 import json
 import os
@@ -36,19 +37,165 @@ from repro.store.tensorstore import MODEL_MANIFEST, TENSOR_DIR, CheckpointStore
 from repro.testing.chaos import chaos_point
 
 
+#: blocks a writer gathers into one file write and one streaming-hash
+#: update: each GIL-releasing call is a chance to wait for the GIL, so the
+#: writer makes one write and the tensor lane one update per chunk
+CHUNK_BLOCKS = 8
+#: output blocks a writer holds gathered, or written but not yet hashed
+#: by both of its hash lanes: one chunk filling and one on the lanes,
+#: resident beside the write-behind queue's ``write_queue_blocks``
+HASH_LANE_BLOCKS = 2 * CHUNK_BLOCKS
+
+
+def block_digest(raw) -> str:
+    """A block's content hash: its journal record and its entry in the
+    tensor's ``block_hashes``."""
+    return hashlib.blake2b(raw, digest_size=8).hexdigest()
+
+
+class _HashLanes:
+    """Two threads that hash one tensor's written chunks behind the thread
+    that writes them.  The tensor lane feeds each chunk to the streaming
+    blake2b-16, in order; the block lane appends each block's
+    :func:`block_digest` to ``digests``, in block order.  hashlib
+    releases the GIL on buffers over 2 KiB, so both overlap the writer's
+    file writes.
+
+    :meth:`submit` first waits until the lanes have hashed every chunk
+    submitted before, so at most one chunk is on the lanes.  A lane's
+    failure is raised by :meth:`submit` and returned by :meth:`join`.
+    ``wait_ns`` is the writer's time blocked on the lanes, ``hash_ns``
+    the lanes' hashing time.
+    """
+
+    def __init__(self, tensor_hash):
+        self.tensor_hash = tensor_hash
+        self.digests: List[str] = []
+        self.wait_ns = 0
+        self._lane_ns = [0, 0]
+        self._submitted = 0  # guarded-by: _cv
+        self._last = 0  # blocks of the last chunk submitted; guarded-by: _cv
+        self._done = [0, 0]  # guarded-by: _cv
+        self._exc: Optional[BaseException] = None  # guarded-by: _cv
+        self._halt = False
+        self._cv = threading.Condition()
+        self._queues = (queue.SimpleQueue(), queue.SimpleQueue())
+        self._threads = [
+            threading.Thread(target=self._run, args=(lane,), daemon=True,
+                             name=f"mergepipe-hash-{name}")
+            for lane, name in enumerate(("tensor", "block"))
+        ]
+        for t in self._threads:
+            t.start()
+
+    @property
+    def hash_ns(self) -> int:
+        return sum(self._lane_ns)
+
+    @property
+    def held(self) -> int:
+        """Blocks submitted and not yet hashed by both lanes."""
+        with self._cv:
+            return self._last if min(self._done) < self._submitted else 0
+
+    def _run(self, lane: int) -> None:
+        q = self._queues[lane]
+        while True:
+            item = q.get()
+            if item is None or self._halt:
+                return
+            chunk, sizes = item
+            try:
+                t0 = trace.now()
+                if lane == 0:
+                    self.tensor_hash.update(chunk)
+                else:
+                    off = 0
+                    with memoryview(chunk) as view:
+                        for n in sizes:
+                            self.digests.append(
+                                block_digest(view[off:off + n]))
+                            off += n
+                self._lane_ns[lane] += trace.now() - t0
+            # broad-except-ok: nothing is swallowed — the error is parked
+            # on self._exc and raised on the writer by its next submit or
+            # finish_tensor, so it reaches the write-behind and abort paths
+            except BaseException as e:  # noqa: BLE001
+                with self._cv:
+                    self._exc = e
+                    self._cv.notify_all()
+                return
+            del item, chunk  # hold no chunk while waiting for the next
+            with self._cv:
+                self._done[lane] += 1
+                self._cv.notify_all()
+
+    # unguarded-ok: caller holds _cv
+    def _settle(self) -> Optional[BaseException]:
+        """With ``_cv`` held: wait until both lanes have hashed every
+        submitted chunk, or one has failed; return the lane's failure."""
+        t0 = trace.now()
+        while self._exc is None and min(self._done) < self._submitted:
+            self._cv.wait()
+        self.wait_ns += trace.now() - t0
+        return self._exc
+
+    def submit(self, chunk: bytearray, sizes: List[int]) -> None:
+        """Hand the lanes one written chunk of blocks of ``sizes`` bytes;
+        the writer mutates it no more."""
+        with self._cv:
+            exc = self._settle()
+            if exc is not None:
+                raise exc
+            self._submitted += 1
+            self._last = len(sizes)
+        for q in self._queues:
+            q.put((chunk, sizes))
+
+    def join(self) -> Optional[BaseException]:
+        """Wait until both lanes have hashed every submitted chunk, or one
+        has failed, then end them; return the lane's failure."""
+        with self._cv:
+            exc = self._settle()
+        self.halt()
+        return exc
+
+    def halt(self) -> None:
+        """End both lanes, dropping chunks not yet hashed."""
+        self._halt = True
+        for q in self._queues:
+            q.put(None)
+        for t in self._threads:
+            t.join()
+
+
 class StagingWriter:
     """Streams output blocks sequentially per tensor into a staging dir.
 
     The executor (Algorithm 2) materializes every output block in plan
-    order; this writer appends them, maintaining streaming hashes so
-    ``ValidateHashes`` never needs to re-read the data files.
+    order; this writer appends them and keeps each tensor's streaming
+    blake2b-16 and per-block blake2b-8 digests.  ``validate_hashes``
+    re-reads the staged files against the streaming hashes before
+    publish: that re-read is the torn-write check.
+
+    The calling thread only gathers and writes: it writes the blocks in
+    chunks of :data:`CHUNK_BLOCKS`, and two hash lanes
+    (:class:`_HashLanes`, started per tensor and ended by its
+    ``finish_tensor``, ``abort`` or ``detach``) compute both digests of
+    each written chunk while the next one gathers.  At most
+    :data:`HASH_LANE_BLOCKS` blocks are gathered or on the lanes.
 
     With a ``journal`` attached, every block append is also recorded in
     the durable progress journal (content hash + contributing experts),
-    making a crash resumable.  With a ``resume`` state, tensors the dead
-    run already (partially) staged are reopened in place: the file is
-    truncated to the validated prefix, the streaming hash is seeded from
-    the validation pass, and writes continue at the high-water block.
+    making a crash resumable.  A block's record is appended, in block
+    order, once its bytes are written and its digest is known, so the
+    records may lag the blocks handed in by a chunk and the lanes'
+    backlog; ``finish_tensor`` writes the last chunk and appends the
+    records still owed before the tensor's ``finish`` record.
+    With a ``resume`` state, tensors the dead run already (partially)
+    staged are reopened in place: the file is truncated to the validated
+    prefix, the streaming hash is seeded from the validation pass, and
+    writes continue at the high-water block.
     """
 
     def __init__(
@@ -65,12 +212,20 @@ class StagingWriter:
         self.specs: Dict[str, Dict] = {}
         self._open_name: Optional[str] = None
         self._open_file = None
-        self._open_hash = None
+        self._lanes: Optional[_HashLanes] = None
+        # the open tensor's gathered blocks, not yet written, and their sizes
+        self._chunk = bytearray()
+        self._chunk_sizes: List[int] = []
+        # (block_idx, nbytes, experts) of the open tensor's blocks whose
+        # digests are not yet recorded, in block order
+        self._owed: collections.deque = collections.deque()
         self._block_hashes: List[str] = []
         self._written = 0
         self._next_block = 0
         self._resume_tensors = dict(resume.tensors) if resume is not None else {}
         self._tensor_count = resume.n_tensor_files if resume is not None else 0
+        #: most blocks gathered or on the hash lanes at once
+        self.peak_hash_lane_blocks = 0
         self.aborted = False
 
     # -- per-tensor streaming ------------------------------------------------
@@ -90,7 +245,7 @@ class StagingWriter:
             f.truncate(tr.validated_nbytes)
             f.seek(tr.validated_nbytes)
             self._open_file = f
-            self._open_hash = tr.hash_obj.copy()
+            tensor_hash = tr.hash_obj.copy()
             self._block_hashes = list(tr.block_hashes)
             self._written = tr.validated_nbytes
             self._next_block = tr.n_validated
@@ -98,13 +253,16 @@ class StagingWriter:
             fname = os.path.join(TENSOR_DIR, f"{self._tensor_count:05d}.bin")
             self._tensor_count += 1
             self._open_file = open(os.path.join(self.dir, fname), "wb")
-            self._open_hash = hashlib.blake2b(digest_size=16)
+            tensor_hash = hashlib.blake2b(digest_size=16)
             self._block_hashes = []
             self._written = 0
             self._next_block = 0
         self._open_name = tensor_id
-        # write and hash time of the open tensor, counted when it finishes
-        self._write_ns = self._hash_ns = 0
+        self._write_ns = 0  # write time of the open tensor, counted at finish
+        self._lanes = _HashLanes(tensor_hash)
+        self._chunk, self._chunk_sizes = bytearray(), []
+        self._owed.clear()
+        self._recorded = 0  # digests of self._lanes recorded so far
         self.specs[tensor_id] = {
             "shape": list(shape),
             "dtype": dtypes.dtype_name(dtype),
@@ -135,30 +293,65 @@ class StagingWriter:
                 f"blocks must stream in order: expected {self._next_block}, "
                 f"got {block_idx}"
             )
-        raw = np.ascontiguousarray(block).tobytes()
-        t0 = trace.now()
-        self._open_file.write(raw)
-        t1 = trace.now()
-        self._open_hash.update(raw)
-        h8 = hashlib.blake2b(raw, digest_size=8).hexdigest()
-        self._write_ns += t1 - t0
-        self._hash_ns += trace.now() - t1
-        self._block_hashes.append(h8)
-        self._written += len(raw)
+        raw = np.ascontiguousarray(block).reshape(-1).view(np.uint8)
+        self._chunk += memoryview(raw)
+        self._chunk_sizes.append(raw.nbytes)
+        self._owed.append((block_idx, raw.nbytes, experts))
+        self._written += raw.nbytes
         self._next_block += 1
-        self.stats.record_write("out", len(raw))
-        if self.journal is not None:
-            self.journal.block(tensor_id, block_idx, len(raw), h8, experts)
+        self.peak_hash_lane_blocks = max(
+            self.peak_hash_lane_blocks,
+            len(self._chunk_sizes) + self._lanes.held)
+        if len(self._chunk_sizes) == CHUNK_BLOCKS:
+            self._flush()
+        else:
+            self._record_hashed()
+
+    def _flush(self) -> None:
+        """Write the gathered chunk, hand it to the lanes, and record the
+        blocks whose digests are known."""
+        chunk, sizes = self._chunk, self._chunk_sizes
+        if not sizes:
+            return
+        self._chunk, self._chunk_sizes = bytearray(), []
+        t0 = trace.now()
+        self._open_file.write(chunk)
+        self._write_ns += trace.now() - t0
+        for n in sizes:
+            self.stats.record_write("out", n)
+        self._lanes.submit(chunk, sizes)
+        self._record_hashed()
+
+    def _record_hashed(self) -> None:
+        """Record, in block order, every owed block whose digest the block
+        lane has computed."""
+        for h8 in self._lanes.digests[self._recorded:]:
+            block_idx, nbytes, experts = self._owed[0]
+            if self.journal is not None:
+                self.journal.block(
+                    self._open_name, block_idx, nbytes, h8, experts
+                )
+            self._owed.popleft()
+            self._recorded += 1
+            self._block_hashes.append(h8)
 
     def finish_tensor(self, tensor_id: str) -> None:
         if tensor_id != self._open_name:
             raise RuntimeError(f"tensor {tensor_id} is not the open tensor")
+        self._flush()
         self._open_file.close()
+        lanes = self._lanes
+        exc = lanes.join()
+        if exc is not None:
+            raise exc
+        self._record_hashed()
+        self._lanes = None
         trace.count("write_ns", self._write_ns)
-        trace.count("hash_ns", self._hash_ns)
+        trace.count("hash_ns", lanes.hash_ns)
+        trace.count("hash_wait_ns", lanes.wait_ns)
         spec = self.specs[tensor_id]
         spec["nbytes"] = self._written
-        spec["hash"] = self._open_hash.hexdigest()
+        spec["hash"] = lanes.tensor_hash.hexdigest()
         self._open_name = None
         self._open_file = None
         if self.journal is not None:
@@ -186,6 +379,9 @@ class StagingWriter:
                 raise IOError(f"hash validation failed for staged tensor {tensor_id}")
 
     def abort(self) -> None:
+        if self._lanes is not None:
+            self._lanes.halt()
+            self._lanes = None
         if self._open_file is not None:
             self._open_file.close()
             self._open_file = None
@@ -200,13 +396,26 @@ class StagingWriter:
     def detach(self) -> None:
         """Close open handles WITHOUT deleting staged data or the journal
         — the in-process analogue of a worker death.  Used by the
-        service's crash handling and the chaos harness before resuming."""
-        if self._open_file is not None:
-            self._open_file.close()
-            self._open_file = None
-            self._open_name = None
-        if self.journal is not None:
-            self.journal.close()
+        service's crash handling and the chaos harness before resuming.
+        The open tensor's gathered blocks are written, and its blocks on
+        the hash lanes hashed and journaled, first, so a resume skips every
+        block handed to this writer (a lane that failed leaves the records
+        from its failure on unwritten)."""
+        try:
+            if self._lanes is not None:
+                self._flush()
+                self._lanes.join()
+                self._record_hashed()
+        finally:
+            if self._lanes is not None:
+                self._lanes.halt()
+                self._lanes = None
+            if self._open_file is not None:
+                self._open_file.close()
+                self._open_file = None
+                self._open_name = None
+            if self.journal is not None:
+                self.journal.close()
 
 
 class WriteBehindWriter:
@@ -216,8 +425,10 @@ class WriteBehindWriter:
     ``begin_tensor`` / ``write_block`` / ``finish_tensor`` enqueue
     commands onto a bounded queue drained *in order* by one writer
     thread, so output-file writes overlap the next window's reads and
-    compute.  Ordering, streaming hashes, and I/O accounting are exactly
-    the wrapped writer's — the commands replay verbatim, just later.
+    compute.  Ordering, streaming hashes, journal records and I/O
+    accounting are exactly the wrapped writer's — the commands replay
+    verbatim, just later; the wrapped writer's hash lanes hash behind
+    this thread.
 
     A failure on the writer thread is re-raised on the producer side at
     the next enqueue (or at :meth:`flush`), so the executor's abort path

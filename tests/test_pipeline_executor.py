@@ -2,6 +2,8 @@
 write-behind): bit-identity with the stream path, identical I/O
 accounting, budget soundness, crash-safety mid-pipeline, and the
 bounded-memory invariant (no whole-tensor buffering)."""
+import time
+
 import numpy as np
 import pytest
 
@@ -263,6 +265,54 @@ def test_bounded_memory_no_whole_tensor_buffering(tmp_path):
     # decisively below whole-tensor buffering (base + K experts resident)
     assert pipe["peak_resident_blocks"] < n_blocks_big
     assert pipe["peak_write_queue_blocks"] <= pipe["write_queue_bound"]
+    assert 1 <= pipe["peak_hash_lane_blocks"] <= pipe["hash_lane_bound"]
+    mp.close()
+
+
+@pytest.mark.parametrize("slow", ["disk", "hash"])
+def test_bounded_output_under_a_slow_writer(tmp_path, monkeypatch, slow):
+    """Resident output stays within ``write_queue_blocks`` queued plus
+    ``HASH_LANE_BLOCKS`` on the writer's hash lanes, whether the disk
+    (the writer thread) or the hashing (the lanes) is the slow side;
+    the slow side fills its own bound and no more."""
+    from repro.store import snapshot
+
+    if slow == "disk":
+        real = StagingWriter.write_block
+
+        def slow_write(self, *args, **kw):
+            time.sleep(0.001)
+            return real(self, *args, **kw)
+        monkeypatch.setattr(StagingWriter, "write_block", slow_write)
+    else:
+        real_digest = snapshot.block_digest
+
+        def slow_digest(raw):
+            time.sleep(0.001)
+            return real_digest(raw)
+        monkeypatch.setattr(snapshot, "block_digest", slow_digest)
+    mp = MergePipe(str(tmp_path), block_size=1024, stats=IOStats())
+    base, experts = make_models(shapes={"big": (512, 96), "b2": (256, 96)})
+    mp.register_model("base", base)
+    ids = []
+    for i, e in enumerate(experts):
+        mp.register_model(f"e{i}", e)
+        ids.append(f"e{i}")
+    res = mp.merge("base", ids, "ta", budget=None, compute="pipelined",
+                   pipeline=PipelineConfig(window_blocks=4, read_threads=3,
+                                           write_queue_blocks=8))
+    pipe = res.stats["pipeline"]
+    assert pipe["write_queue_bound"] == 8
+    assert pipe["hash_lane_bound"] == snapshot.HASH_LANE_BLOCKS
+    assert pipe["peak_write_queue_blocks"] == pipe["write_queue_bound"]
+    assert pipe["peak_hash_lane_blocks"] <= pipe["hash_lane_bound"]
+    if slow == "hash":
+        assert pipe["peak_hash_lane_blocks"] == pipe["hash_lane_bound"]
+    assert (pipe["peak_write_queue_blocks"] + pipe["peak_hash_lane_blocks"]
+            <= pipe["write_queue_bound"] + pipe["hash_lane_bound"])
+    # the bytes are those of an unhurried stream run
+    ref = mp.merge("base", ids, "ta", budget=None, compute="stream")
+    assert _tensor_hashes(mp, res.sid) == _tensor_hashes(mp, ref.sid)
     mp.close()
 
 

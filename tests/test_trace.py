@@ -81,7 +81,8 @@ def test_pipelined_merge_records_every_stage(tmp_path, recorders, kernel):
         tr["spans"][trace.EXECUTE]["seconds"])
     assert res.stats["plan"]["plan_seconds"] > 0
     for key in ("read_bytes.base", "read_bytes.expert", "verify_bytes",
-                "pull_ns", "write_ns", "hash_ns", "fsync_ns"):
+                "pull_ns", "write_ns", "hash_ns", "hash_wait_ns",
+                "fsync_ns"):
         assert tr["counters"][key] > 0, key
     assert ("kernel_calls" in tr["counters"]) == (kernel == "jax")
 
